@@ -70,9 +70,10 @@ dispatches that reassemble bit-identically on the destination.  Bulk
 speculative prefetch is disabled under streaming (channels already overlap
 chunk-wise); ``streaming=False`` keeps the bulk path bit-identical.
 
-On this 1-CPU container all groups alias one device (transfers are
-no-op-counted but still exercised; buffer donation is a no-op XLA ignores);
-on a real slice, groups are disjoint device sets.
+On one device all groups alias it (transfers are no-op-counted but still
+exercised; a donated buffer is consumed, which is why ``_donatable`` demands
+the group's copy be the only one); on a real slice, groups are disjoint
+device sets.
 """
 
 from __future__ import annotations
@@ -117,6 +118,7 @@ class ExecResult:
     #                                   # fewer with async_groups wave overlap)
     overlap_ms: float = 0.0  # virtual compute time co-scheduled inside waves
     #                                   # (sum of member spans minus wave span)
+    n_donated: int = 0  # donated input buffers the fused calls consumed
 
 
 @dataclasses.dataclass
@@ -241,6 +243,7 @@ class ExecSession:
         self.fused_steps = 0
         self.cache_hits = 0
         self.cache_misses = 0
+        self.n_donated = 0
         self.superstep_runs: list[SuperStepRun] = []
         self._fused_buf: list[KernelRun] = []
         # gated kernels exist in the graph but may not run until admitted
@@ -781,7 +784,8 @@ class ExecSession:
             )
             specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ext_args]
             with jax.default_device(dev), warnings.catch_warnings():
-                # donation is advisory: backends without aliasing (CPU) warn
+                # donation is advisory: XLA warns about a donated buffer
+                # it cannot alias to an output (n_donated counts the rest)
                 warnings.filterwarnings("ignore", message=".*donated.*")
                 return jax.jit(chain, donate_argnums=donate).lower(*specs).compile()
 
@@ -812,6 +816,7 @@ class ExecSession:
 
         # donated external buffers are consumed: drop the group's copies
         donated = [ext_keys[i] for i in donate]
+        self.n_donated += sum(ext_args[i].is_deleted() for i in donate)
         for key in donated:
             ent = valid.get(key)
             if ent is not None:
@@ -1171,6 +1176,7 @@ class ExecSession:
         for pl in plans:
             grp = pl["grp"]
             donated = [pl["ext_keys"][i] for i in pl["donate"]]
+            self.n_donated += sum(pl["ext_args"][i].is_deleted() for i in pl["donate"])
             for key in donated:
                 ent = valid.get(key)
                 if ent is not None:
@@ -1339,6 +1345,7 @@ class ExecSession:
             n_depth_adjust=self.comm.n_depth_adjust if self.comm else 0,
             n_waves=self.n_waves,
             overlap_ms=self.overlap_ms,
+            n_donated=self.n_donated,
         )
 
 

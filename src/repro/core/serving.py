@@ -105,6 +105,9 @@ class StepReport:
     n_waves: int = 0                # fused dispatch barriers (async_groups:
     #                               # one per wave, else one per group-step)
     overlap_ms: float = 0.0         # compute co-scheduled inside waves
+    n_donated: int = 0              # donated buffers the fused calls consumed
+    # kernel -> the group its last execution ran on (kept out of to_dict)
+    ran_on: dict = dataclasses.field(default_factory=dict, repr=False)
 
 
 @dataclasses.dataclass
@@ -239,7 +242,9 @@ class ServingExecutor:
                  link: Link | None = None, fused: bool = False,
                  superstep_cache: SuperStepCache | None = None,
                  streaming: bool = False, chunk_bytes: int | None = None,
-                 stream_depth: int = 2, async_groups: bool = False):
+                 stream_depth: int = 2, async_groups: bool = False,
+                 check: Callable[[ArenaStep, StepReport, dict], None]
+                 | None = None):
         missing = [c for c in platform.classes if c not in groups]
         if missing:
             raise KeyError(f"platform classes without a device group: {missing}")
@@ -271,6 +276,10 @@ class ServingExecutor:
         # async multi-group waves: fused group-steps whose cross-group inputs
         # are satisfied dispatch in the same wave, one barrier per wave
         self.async_groups = async_groups and fused
+        # check(step, report, outputs) sees each interval's exit outputs
+        # (exit block -> array) before they are released, so a stream never
+        # holds more than one interval of them; it may raise
+        self.check = check
 
     def reset_measurements(self) -> None:
         """Fresh measurement state (monitor EWMAs + cost history).  Called at
@@ -422,6 +431,7 @@ class ServingExecutor:
         dropped: list[str] = []
         added: list[str] = []
         cls_ms: dict[str, list[float]] = {}
+        ran_on: dict[str, str] = {}
         peak_mem: dict[str, float] = {}
         # request-granular KV lifetime: a chain's footprint frees when its
         # whole request has executed (meta["req"], as in the simulator)
@@ -486,6 +496,7 @@ class ServingExecutor:
             # the stream clock follows the session's two-resource timeline
             # (compute overlapped with lane transfers), not a serialized sum
             clock = max(clock, run.t_finish)
+            ran_on[run.name] = run.group
             first = run.name not in state.finished
             state.finished.add(run.name)
             kern = g.nodes[run.name]
@@ -526,7 +537,7 @@ class ServingExecutor:
         if hasattr(policy, "observe_step_ms"):
             feed_policy(policy, self.monitor)
 
-        return StepReport(
+        report = StepReport(
             tag=step.tag,
             n_kernels=sum(session.per_group.values()),
             makespan_ms=max(clock, session.vmax),
@@ -558,7 +569,12 @@ class ServingExecutor:
             stream_busy_ms=comm.stream_busy_ms,
             n_waves=session.n_waves,
             overlap_ms=session.overlap_ms,
+            n_donated=session.n_donated,
+            ran_on=ran_on,
         )
+        if self.check is not None:
+            self.check(step, report, session.result().outputs)
+        return report
 
     # -- whole stream ----------------------------------------------------------
 

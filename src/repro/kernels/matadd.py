@@ -20,8 +20,11 @@ def _add_kernel(a_ref, b_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def matadd(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
            interpret: bool = False) -> jax.Array:
+    """Dims must be (8, 128) multiples (the ops.py wrapper pads): the gcd
+    blocks below are then (8, 128)-aligned, as Mosaic requires."""
     assert a.shape == b.shape
     M, N = a.shape
+    assert M % 8 == 0 and N % 128 == 0, (M, N)
     import math
     bm = math.gcd(M, min(bm, M))
     bn = math.gcd(N, min(bn, N))

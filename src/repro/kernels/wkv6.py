@@ -25,7 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *, S):
     s_ref[...] = jnp.zeros_like(s_ref)
-    u = u_ref[0]                                          # (N,)
+    u = u_ref[0, 0]                                       # (N,)
 
     def step(t, _):
         rt = r_ref[0, 0, t]                               # (N,)
@@ -49,11 +49,14 @@ def wkv6(r, k, v, w, u, *, interpret: bool = False):
     B, H, S, N = r.shape
     grid = (B, H)
     seq_spec = pl.BlockSpec((1, 1, S, N), lambda b, h: (b, h, 0, 0))
+    # u as (H, 1, N): a (1, 1, N) block spans the full last two dims, which
+    # Mosaic accepts for any H (a (1, N) block of (H, N) does not)
+    u3 = u.reshape(H, 1, N)
     return pl.pallas_call(
         functools.partial(_wkv_kernel, S=S),
         grid=grid,
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, N), lambda b, h: (h, 0))],
+                  pl.BlockSpec((1, 1, N), lambda b, h: (h, 0, 0))],
         out_specs=seq_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, N), r.dtype),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
@@ -61,4 +64,4 @@ def wkv6(r, k, v, w, u, *, interpret: bool = False):
             dimension_semantics=("parallel", "parallel"))
         if not interpret else None,
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u3)

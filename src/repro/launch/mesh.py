@@ -13,15 +13,25 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the models place work with
+    sharding constraints and leave the rest to the partitioner, so no axis
+    may be ``Explicit`` (the type-level sharding ``jax.make_mesh`` defaults
+    to, under which gathers and contractions over sharded dims must name
+    their output sharding)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs (axis names kept compatible)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants (assignment brief)

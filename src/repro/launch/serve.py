@@ -24,8 +24,10 @@ Two layers, mirroring the paper's stack:
   # measured per-kernel times feeding back into the online targets; metrics
   # land in BENCH_serve.json (the CI bench-smoke gate consumes it).  --fused
   # dispatches each partition group's kernel chain as ONE compiled
-  # super-step (async dispatch, one barrier per group-step, persistent
-  # compilation cache) instead of the kernel-at-a-time loop:
+  # super-step (async dispatch, one barrier per group-step, in-process
+  # super-step cache) instead of the kernel-at-a-time loop.  XLA's on-disk
+  # compilation cache lives where JAX_COMPILATION_CACHE_DIR says, else in
+  # <repo>/.jax_cache (repro.launch.compile_cache):
   PYTHONPATH=src python -m repro.launch.serve --arena --execute --fused
 """
 
@@ -56,6 +58,7 @@ from repro.core.router import MODES, ReplicaRouter, RouterReport, SimReplica
 from repro.core.schedulers import as_executed, make_policy
 from repro.core.serving import ServingExecutor, groups_for_platform
 from repro.core.simulate import Platform, Processor, WorkerDrop, simulate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import DistConfig
 from repro.models import transformer as T
 from repro.models.params import init_params
@@ -354,6 +357,7 @@ def run_arena_executed(
     hier: bool = False,
     fused: bool = False,
     async_groups: bool = False,
+    check=None,
 ) -> tuple[list, SchedulerArena]:
     """The arena stream EXECUTED on real device groups.
 
@@ -367,10 +371,12 @@ def run_arena_executed(
     (shared-uplink contention + prefetch throttling), matching the
     simulated ``run_arena(hier=True)`` stream.  ``fused=True`` dispatches
     each group's runnable kernel chain as one compiled super-step (async
-    dispatch + persistent compilation cache) instead of kernel-at-a-time;
+    dispatch + in-process super-step cache) instead of kernel-at-a-time;
     ``async_groups=True`` additionally dispatches every group whose
     cross-group inputs are satisfied in the same dependency wave — one
-    barrier per wave instead of per group (requires ``fused``)."""
+    barrier per wave instead of per group (requires ``fused``).
+    ``check(step, report, outputs)`` sees every interval's exit outputs
+    (:class:`~repro.core.serving.ServingExecutor`)."""
     plat, drop_proc, costs_prefill, costs_decode = _arena_setup(hier, drop_proc)
     events_at = {}
     if drop_step is not None:
@@ -390,7 +396,8 @@ def run_arena_executed(
         events_at=events_at,
     )
     executor = ServingExecutor(groups_for_platform(plat), plat, side=side,
-                               fused=fused, async_groups=async_groups)
+                               fused=fused, async_groups=async_groups,
+                               check=check)
     factories = {
         p: (lambda n=p: as_executed(make_policy(n, **_policy_kwargs(n))))
         for p in policies
@@ -567,7 +574,7 @@ def main(argv=None):
         default=False,
         help="with --execute: dispatch each partition group's kernel "
         "chain as ONE jitted, buffer-donating super-step (one barrier "
-        "per group-step + persistent compilation cache) instead of the "
+        "per group-step + in-process super-step cache) instead of the "
         "kernel-at-a-time loop; --no-fused is the bit-identical "
         "fallback the CI baseline pins",
     )
@@ -595,6 +602,7 @@ def main(argv=None):
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.arena and args.replicas > 1:
         modes = list(MODES) if args.router == "all" else [args.router]
@@ -685,7 +693,8 @@ def main(argv=None):
         )
         print(
             f"[serve] {cfg.name}: {args.requests} requests x "
-            f"{args.decode_len} tokens -> {tps:.1f} tok/s (CPU)"
+            f"{args.decode_len} tokens -> {tps:.1f} tok/s "
+            f"({jax.devices()[0].platform})"
         )
     for pol in [args.scheduler] if args.scheduler else []:
         r = schedule_requests(args.requests, args.decode_chunks, pol)

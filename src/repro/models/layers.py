@@ -596,7 +596,7 @@ def decode_attn_seqpar(q, ck, cv, k_new, v_new, pos, *, ctx: Ctx, logit_cap=0.0)
         o = o_g / jnp.maximum(l_g, 1e-30)[..., None]
         return o.reshape(-1, H, hd).astype(q.dtype), ck, cv
 
-    from ..compat import shard_map
+    from jax import shard_map
 
     f = shard_map(
         local,

@@ -25,9 +25,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
-
-from ..compat import shard_map
 
 from .params import P
 from .layers import Ctx

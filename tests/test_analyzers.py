@@ -117,7 +117,8 @@ def test_hlo_collectives_on_forced_multidevice():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as PS, NamedSharding
         from repro.launch import hlo as H
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         w_sh = NamedSharding(mesh, PS(None, "model"))
         x_sh = NamedSharding(mesh, PS("data", None))
         def f(w, x):

@@ -46,6 +46,9 @@ def churn_reports():
         side=16,
         drop_step=DROP_STEP,
         drop_proc="small1",
+        # early in the interval: a fast host drains a 3-request interval
+        # in under a virtual millisecond, and a later drop would miss it
+        drop_t_ms=0.05,
         policies=("eager", "dmda", "heft", "affinity-steal"),
     )
     return rows, arena

@@ -129,3 +129,31 @@ def test_model_flash_oracle_matches_kernel():
     got = out.reshape(B, Sq, K * G, hd).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_ops_device_probe_raises_instead_of_falling_back(monkeypatch):
+    """A backend that fails to come up is an error, not a silent switch to
+    the jnp oracle."""
+    from repro.kernels import ops
+
+    def broken():
+        raise RuntimeError("backend failed to initialise")
+
+    monkeypatch.setattr(ops, "KERNEL_MODE", "auto")
+    monkeypatch.setattr(ops.jax, "devices", broken)
+    a = jnp.ones((8, 8))
+    for fn in (ops.matmul, ops.matadd):
+        with pytest.raises(RuntimeError, match="failed to initialise"):
+            fn(a, a)
+
+
+@pytest.mark.parametrize("side", [48, 100, 1000])
+def test_ops_matadd_padded_pallas_matches_oracle(monkeypatch, side):
+    """The padded Pallas path (interpret mode here) returns the unpadded sum."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "KERNEL_MODE", "pallas")
+    a = jax.random.normal(jax.random.PRNGKey(0), (side, side))
+    b = jax.random.normal(jax.random.PRNGKey(1), (side, side))
+    np.testing.assert_array_equal(np.asarray(ops.matadd(a, b)),
+                                  np.asarray(a + b))

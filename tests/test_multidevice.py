@@ -22,7 +22,7 @@ def _run(code: str):
          'import os\nos.environ["XLA_FLAGS"] = '
          '"--xla_force_host_platform_device_count=8"\n'
          'import sys\nsys.path.insert(0, "src")\n'
-         'from repro import compat\n' + textwrap.dedent(code)],
+         'from repro.launch.mesh import make_mesh\n' + textwrap.dedent(code)],
         capture_output=True, text=True, cwd=ROOT, timeout=420)
     assert "PASS" in r.stdout, (r.stdout[-2000:], r.stderr[-3000:])
 
@@ -35,7 +35,7 @@ def test_moe_ep_matches_reference():
     from repro.models.layers import Ctx
     from repro.models.params import init_params
     from repro.parallel.sharding import TRAIN_RULES
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(name="t", family="m", d_model=32, n_layers=1,
                       n_heads=4, n_kv_heads=2, d_ff=64, vocab=64,
                       unit=(LayerSpec("attn", "moe"),), n_experts=8,
@@ -45,7 +45,7 @@ def test_moe_ep_matches_reference():
     p = init_params(M.moe_params(cfg, tp=4), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
     ref_out, ref_aux = M.moe_ref(p, x, cfg, ctx1)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         ep_out, ep_aux = jax.jit(
             lambda p, x: M.moe_ep(p, x, cfg, ctx8,
                                   capacity_factor=8.0))(p, x)
@@ -66,7 +66,7 @@ def test_moe_ep_expert_perm_preserves_output():
     from repro.models.layers import Ctx
     from repro.models.params import init_params
     from repro.parallel.sharding import TRAIN_RULES
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(name="t", family="m", d_model=32, n_layers=1,
                       n_heads=4, n_kv_heads=2, d_ff=64, vocab=64,
                       unit=(LayerSpec("attn", "moe"),), n_experts=8,
@@ -80,7 +80,7 @@ def test_moe_ep_expert_perm_preserves_output():
     p2 = dict(p)
     for k in ("w_gate", "w_up", "w_down"):
         p2[k] = p[k][inv]
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         base, _ = jax.jit(lambda p, x: M.moe_ep(p, x, cfg, ctx,
                                                 capacity_factor=8.0))(p, x)
         permed, _ = jax.jit(lambda p, x: M.moe_ep(
@@ -98,7 +98,7 @@ def test_flash_decode_seqpar_matches_dense():
     from repro.models import layers as L
     from repro.models.layers import Ctx
     from repro.parallel.sharding import DECODE_RULES
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     B, S, K, G, hd = 4, 64, 2, 2, 16
     H = K * G
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -111,7 +111,7 @@ def test_flash_decode_seqpar_matches_dense():
     ctx = Ctx(rules=DECODE_RULES, dtype=jnp.float32, mesh=mesh,
               decode_seqpar=True)
     dense_o, (dk, dv) = L.decode_attn_dense(q, ck, cv, kn, vn, pos)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         sp_o, (sk, sv) = jax.jit(lambda *a: L.decode_attn_seqpar(
             *a, ctx=ctx))(q, ck, cv, kn, vn, pos)
     np.testing.assert_allclose(np.asarray(sp_o), np.asarray(dense_o),
@@ -126,7 +126,7 @@ def test_sharding_trees_drop_nondivisible_axes():
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as PS
     from repro.parallel.sharding import spec_for, rules_for
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = rules_for(type("C", (), {"fsdp": False})(), "train")
     # batch=1 cannot shard: dp axes dropped
     assert spec_for(("batch", "seq"), rules, mesh, (1, 64)) == PS()
@@ -152,7 +152,7 @@ def test_train_step_runs_on_8_devices():
                                     param_shardings, shardings_for_batch,
                                     replicated)
     from repro.models.params import init_params
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = dataclasses.replace(get_config("granite_3_2b").smoke(),
                               activation_dtype="float32")
     step, p_specs, o_specs, ctx = make_train_step(cfg, mesh, DistConfig())
@@ -166,7 +166,7 @@ def test_train_step_runs_on_8_devices():
     fn = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                  out_shardings=(p_sh, o_sh, replicated(mesh)),
                  donate_argnums=(0, 1))
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params, opt, m = fn(params, opt, batch)
         params, opt, m = fn(params, opt, batch)
     assert jnp.isfinite(m["loss"]), m
@@ -184,7 +184,7 @@ def test_moe_ep_dedup_matches_reference():
     from repro.models.layers import Ctx
     from repro.models.params import init_params
     from repro.parallel.sharding import TRAIN_RULES
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(name="t", family="m", d_model=32, n_layers=1,
                       n_heads=4, n_kv_heads=2, d_ff=64, vocab=64,
                       unit=(LayerSpec("attn", "moe"),), n_experts=8,
@@ -194,7 +194,7 @@ def test_moe_ep_dedup_matches_reference():
     p = init_params(M.moe_params(cfg, tp=4), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
     ref_out, _ = M.moe_ref(p, x, cfg, ctx1)
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         dd_out, _ = jax.jit(lambda p, x: M.moe_ep_dedup(
             p, x, cfg, ctx8, dest_k=3.0, capacity_factor=8.0))(p, x)
         perm = jnp.array([0, 4, 1, 5, 2, 6, 3, 7])
@@ -209,5 +209,33 @@ def test_moe_ep_dedup_matches_reference():
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(pd_out), np.asarray(ref_out),
                                rtol=2e-4, atol=2e-4)
+    print("PASS")
+    """)
+
+
+@pytest.mark.parametrize("mode", ["{}", "{'fused': True, 'async_groups': True}"],
+                         ids=["unfused", "fused+waves"])
+def test_executed_hier_stream_places_outputs_per_group(mode):
+    """The rack/pod stream with one device per class: outputs equal the
+    reference, each lives on the device of the group that produced it, and
+    blocks really cross devices."""
+    _run(f"""
+    import jax
+    from repro.core.reference import interval_error
+    from repro.core.serving import groups_for_platform
+    from repro.launch.serve import hierarchical_platform, run_arena_executed
+    groups = groups_for_platform(hierarchical_platform())
+    assert len(set(groups.values())) == 4, groups
+    held, errs = set(), []
+    def check(step, report, outputs):
+        for name, arr in outputs.items():
+            assert arr.devices() == {{groups[report.ran_on[name]]}}, name
+            held.add(groups[report.ran_on[name]])
+        errs.append(interval_error(step, outputs, 16))
+    _, arena = run_arena_executed(6, 3, steps=3, drop_step=1, seed=0,
+                                  hier=True, side=16, policies=("dmda",),
+                                  check=check, **{mode})
+    assert len(errs) == 3 and max(errs) < 1e-6
+    assert len(held) > 1 and arena.reports["dmda"].to_dict()["transfers"] > 0
     print("PASS")
     """)

@@ -21,6 +21,7 @@ from repro.core.cost import MeasuredCostModel
 from repro.core.executor import JaxExecutor, attach_request_kernels
 from repro.core.graph import TaskGraph
 from repro.core.online import IncrementalGpPolicy
+from repro.core.reference import interval_error
 from repro.core.schedulers import make_policy
 from repro.core.serving import ServingExecutor, groups_for_platform, subgraph_of
 from repro.core.simulate import WorkerDrop
@@ -241,3 +242,56 @@ def test_run_arena_executed_rows_and_bench_gate(tmp_path):
     incomplete = copy.deepcopy(doc)
     incomplete["executed"]["gp"]["kernels"] -= 1
     assert gate_check(incomplete, doc, 0.20)
+
+
+# -- executed outputs vs the plain reference ----------------------------------
+
+@pytest.mark.parametrize("mode", [{}, {"fused": True},
+                                  {"fused": True, "async_groups": True}],
+                         ids=["unfused", "fused", "fused+waves"])
+def test_executed_outputs_match_reference(mode):
+    """Every interval's exit outputs — through a mid-stream drop that kills
+    the big class (its group is evicted) — equal a plain topological
+    evaluation of its DAG, handed to the check hook as each interval ends."""
+    seen = []
+
+    def check(step, report, outputs):
+        assert set(outputs) == set(step.graph.exit_nodes())
+        assert set(report.ran_on) == set(step.graph.nodes)
+        seen.append(interval_error(step, outputs, 16))
+
+    _, arena = run_arena_executed(5, 3, steps=3, drop_step=1, drop_proc="big0",
+                                  drop_t_ms=0.05, seed=0, side=16, check=check,
+                                  policies=("incremental-gp", "dmda"), **mode)
+    assert len(seen) == 2 * 3 and max(seen) < 1e-6
+    for rep in arena.reports.values():
+        assert "big0" in rep.steps[1].dropped
+        assert "ran_on" not in rep.to_dict()
+
+
+def test_reference_catches_a_wrong_output():
+    def check(step, report, outputs):
+        outs = dict(outputs)
+        name = sorted(outs)[0]
+        outs[name] = outs[name].at[0, 0].add(1e3)
+        assert interval_error(step, outs, 16) > 1e-3
+        del outs[name]
+        with pytest.raises(AssertionError, match="exit blocks differ"):
+            interval_error(step, outs, 16)
+        checked.append(step.tag)
+
+    checked = []
+    run_arena_executed(2, 2, steps=1, side=16, policies=("incremental-gp",),
+                       check=check)
+    assert len(checked) == 1
+
+
+def test_executed_stream_keeps_no_outputs():
+    """Without a check hook nothing of an interval's outputs outlives it;
+    the fused calls consume their donated buffers."""
+    _, arena = run_arena_executed(8, 4, steps=3, seed=0, side=16,
+                                  policies=("dmda", "incremental-gp"),
+                                  fused=True, async_groups=True)
+    reps = arena.reports.values()
+    assert not any(hasattr(s, "outputs") for r in reps for s in r.steps)
+    assert sum(r.total("n_donated") for r in reps) > 0
