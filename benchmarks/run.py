@@ -8,7 +8,6 @@ def main() -> None:
     from . import (fig3_kernel_ratio, fig4_transfer_ratio, fig5_ma_task,
                    fig6_mm_task, pipeline_partition_bench, placement_bench,
                    serve_sched_bench)
-    from . import roofline
     print("name,value,derived")
     for mod in (fig3_kernel_ratio, fig4_transfer_ratio, fig5_ma_task,
                 fig6_mm_task, pipeline_partition_bench, placement_bench,
@@ -17,11 +16,6 @@ def main() -> None:
         mod.main()
         print(f"bench.{mod.__name__.split('.')[-1]}.wall_s,"
               f"{time.time()-t0:.1f},", flush=True)
-    # roofline table (from dry-run artifacts, if present)
-    try:
-        roofline.main([])
-    except Exception as e:  # artifacts absent on a fresh checkout
-        print(f"bench.roofline.skipped,0,{type(e).__name__}")
 
 
 if __name__ == "__main__":

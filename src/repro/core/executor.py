@@ -86,6 +86,15 @@ from typing import Iterable, Mapping
 import jax
 
 from .comm import CommEngine
+from .spans import (
+    EXEC_ACCOUNT,
+    EXEC_COMPILE,
+    EXEC_LAUNCH,
+    EXEC_PULL,
+    EXEC_SELECT,
+    EXEC_WAIT,
+    Spans,
+)
 from ..kernels.ops import build_chain
 
 
@@ -190,6 +199,7 @@ class KernelRun:
     nbytes: int  # bytes those transfers moved
     t_start: float = 0.0  # virtual start (comm model attached)
     t_finish: float = 0.0  # virtual finish (compute + overlapped transfers)
+    t_ready: float = 0.0  # perf_counter once its output was ready (or launched)
 
 
 class ExecSession:
@@ -205,6 +215,9 @@ class ExecSession:
     tier of a hierarchical topology) and kernels get virtual start/finish
     times with transfers overlapping compute (``prefetch_depth`` next-ready
     kernels have their inputs staged early).
+
+    ``spans`` records the session's host time by span name
+    (:mod:`repro.core.spans`); the serving loop passes its own recorder.
     """
 
     def __init__(
@@ -228,8 +241,10 @@ class ExecSession:
         stream_depth: int = 2,
         async_groups: bool = False,
         cost_clock: bool = False,
+        spans: Spans | None = None,
     ):
         g.validate()
+        self.spans = spans if spans is not None else Spans()
         self.ex = executor
         self.g = g
         self.assignment = dict(assignment)
@@ -475,7 +490,8 @@ class ExecSession:
                 # drain() (post-dispatch) rewrites it to the last arrival
                 self.vt_block[(key, grp)] = ch.first_ready
                 self._pending_channels.append((key, grp, ch))
-                ent[grp] = self._stream_put(donor, dev, ch.n_chunks)
+                with self.spans(EXEC_PULL):
+                    ent[grp] = self._stream_put(donor, dev, ch.n_chunks)
                 return nb
             # same node: no wire — fall through to the free bulk path
         if self.comm is not None:
@@ -509,7 +525,8 @@ class ExecSession:
             self.vt_block[(key, grp)] = te
             if kind == "prefetch":
                 self.prefetched.add((key, grp))
-        ent[grp] = jax.device_put(donor, dev)
+        with self.spans(EXEC_PULL):
+            ent[grp] = jax.device_put(donor, dev)
         return nb
 
     def _stream_put(self, donor, dev, n_chunks: int):
@@ -526,7 +543,8 @@ class ExecSession:
         for i in range(0, rows, step):
             parts.append(jax.device_put(donor[i : i + step], dev))
             if self.stream_depth and len(parts) > self.stream_depth:
-                parts[-self.stream_depth - 1].block_until_ready()
+                with self.spans(EXEC_WAIT):
+                    parts[-self.stream_depth - 1].block_until_ready()
         import jax.numpy as jnp
 
         with jax.default_device(dev):
@@ -666,42 +684,43 @@ class ExecSession:
         ops: list[str] = []
         costs: list[float] = []
         entries: list[list] = []
-        for n in self._order:
-            if n in done or n in gated:
-                continue
-            n_grp = get_group(n, host)
-            if grp is not None and n_grp != grp:
-                continue
-            preds = predecessors(n)
-            entry: list = []
-            runnable = True
-            for p in preds:
-                j = midx.get(p)
-                if j is not None:
-                    entry.append(j)
-                elif g_nodes[p].op == "source":
-                    entry.append((n + "/in", 0))  # entry kernel: seeded input
-                elif p in done:
-                    entry.append((p, g_edge(p, n).nbytes))
-                else:
-                    runnable = False
-                    break
-            if not runnable:
-                continue
-            if not preds and (n + "/in") in valid:
-                entry.append((n + "/in", 0))  # source-less entry kernel
-            k = g_nodes[n]
-            if k.fn is None:
-                raise ValueError(f"kernel {n} has no fn")
-            if grp is None:
-                grp = n_grp
-                dev = self.ex.groups[grp]
-            midx[n] = len(members)
-            members.append(n)
-            fns.append(k.fn)
-            ops.append(k.op)
-            costs.append(k.costs.get(grp, 0.0))
-            entries.append(entry)
+        with self.spans(EXEC_SELECT):
+            for n in self._order:
+                if n in done or n in gated:
+                    continue
+                n_grp = get_group(n, host)
+                if grp is not None and n_grp != grp:
+                    continue
+                preds = predecessors(n)
+                entry: list = []
+                runnable = True
+                for p in preds:
+                    j = midx.get(p)
+                    if j is not None:
+                        entry.append(j)
+                    elif g_nodes[p].op == "source":
+                        entry.append((n + "/in", 0))  # entry kernel: seeded input
+                    elif p in done:
+                        entry.append((p, g_edge(p, n).nbytes))
+                    else:
+                        runnable = False
+                        break
+                if not runnable:
+                    continue
+                if not preds and (n + "/in") in valid:
+                    entry.append((n + "/in", 0))  # source-less entry kernel
+                k = g_nodes[n]
+                if k.fn is None:
+                    raise ValueError(f"kernel {n} has no fn")
+                if grp is None:
+                    grp = n_grp
+                    dev = self.ex.groups[grp]
+                midx[n] = len(members)
+                members.append(n)
+                fns.append(k.fn)
+                ops.append(k.op)
+                costs.append(k.costs.get(grp, 0.0))
+                entries.append(entry)
         if grp is None:
             return False
         member_set = midx.keys()
@@ -783,7 +802,11 @@ class ExecSession:
                 [(fn, srcs) for fn, (_, srcs) in zip(fns, plan)], keep
             )
             specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ext_args]
-            with jax.default_device(dev), warnings.catch_warnings():
+            with (
+                self.spans(EXEC_COMPILE),
+                jax.default_device(dev),
+                warnings.catch_warnings(),
+            ):
                 # donation is advisory: XLA warns about a donated buffer
                 # it cannot alias to an output (n_donated counts the rest)
                 warnings.filterwarnings("ignore", message=".*donated.*")
@@ -795,24 +818,33 @@ class ExecSession:
 
         ms = 0.0
         tk = self.time_kernels
+        spans = self.spans
         if tk:
             # ONE host sync per group-step, outside the timed region: input
             # production time must not leak into the apportioned kernel times
-            for a in ext_args:
-                if hasattr(a, "block_until_ready"):
-                    a.block_until_ready()
+            with spans(EXEC_WAIT):
+                for a in ext_args:
+                    if hasattr(a, "block_until_ready"):
+                        a.block_until_ready()
             t0 = time.perf_counter()
-        if donate:
-            with warnings.catch_warnings():
-                warnings.filterwarnings("ignore", message=".*donated.*")
+        anchor = members[0]
+        req = g_nodes[anchor].meta.get("req", "")
+        with spans(EXEC_LAUNCH, kernel=anchor, req=req):
+            if donate:
+                with warnings.catch_warnings():
+                    warnings.filterwarnings("ignore", message=".*donated.*")
+                    outs = fn(*ext_args)
+            else:
                 outs = fn(*ext_args)
-        else:
-            outs = fn(*ext_args)
         if tk:
-            for o in outs:
-                if hasattr(o, "block_until_ready"):
-                    o.block_until_ready()
-            ms = (time.perf_counter() - t0) * 1e3
+            with spans(EXEC_WAIT):
+                for o in outs:
+                    if hasattr(o, "block_until_ready"):
+                        o.block_until_ready()
+                t_ready = time.perf_counter()
+            ms = (t_ready - t0) * 1e3
+        else:
+            t_ready = time.perf_counter()
 
         # donated external buffers are consumed: drop the group's copies
         donated = [ext_keys[i] for i in donate]
@@ -867,7 +899,9 @@ class ExecSession:
             done.add(n)
             if record:
                 buf_append(
-                    KernelRun(n, grp, kms, per_nt[i], per_nb[i], vstart, vfinish)
+                    KernelRun(
+                        n, grp, kms, per_nt[i], per_nb[i], vstart, vfinish, t_ready
+                    )
                 )
         self.per_group[grp] = self.per_group.get(grp, 0) + len(members)
         self.fused_steps += 1
@@ -911,66 +945,67 @@ class ExecSession:
         # already-claimed groups excluded)
         plans: list[dict] = []
         claimed: set[str] = set()
-        while True:
-            grp: str | None = None
-            dev = None
-            members: list[str] = []
-            midx: dict[str, int] = {}
-            fns: list = []
-            ops: list[str] = []
-            costs: list[float] = []
-            entries: list[list] = []
-            for n in self._order:
-                if n in done or n in gated:
-                    continue
-                n_grp = get_group(n, host)
-                if n_grp in claimed or (grp is not None and n_grp != grp):
-                    continue
-                preds = predecessors(n)
-                entry: list = []
-                runnable = True
-                for p in preds:
-                    j = midx.get(p)
-                    if j is not None:
-                        entry.append(j)
-                    elif g_nodes[p].op == "source":
+        with self.spans(EXEC_SELECT):
+            while True:
+                grp: str | None = None
+                dev = None
+                members: list[str] = []
+                midx: dict[str, int] = {}
+                fns: list = []
+                ops: list[str] = []
+                costs: list[float] = []
+                entries: list[list] = []
+                for n in self._order:
+                    if n in done or n in gated:
+                        continue
+                    n_grp = get_group(n, host)
+                    if n_grp in claimed or (grp is not None and n_grp != grp):
+                        continue
+                    preds = predecessors(n)
+                    entry: list = []
+                    runnable = True
+                    for p in preds:
+                        j = midx.get(p)
+                        if j is not None:
+                            entry.append(j)
+                        elif g_nodes[p].op == "source":
+                            entry.append((n + "/in", 0))
+                        elif p in done:
+                            entry.append((p, g_edge(p, n).nbytes))
+                        else:
+                            runnable = False
+                            break
+                    if not runnable:
+                        continue
+                    if not preds and (n + "/in") in valid:
                         entry.append((n + "/in", 0))
-                    elif p in done:
-                        entry.append((p, g_edge(p, n).nbytes))
-                    else:
-                        runnable = False
-                        break
-                if not runnable:
-                    continue
-                if not preds and (n + "/in") in valid:
-                    entry.append((n + "/in", 0))
-                k = g_nodes[n]
-                if k.fn is None:
-                    raise ValueError(f"kernel {n} has no fn")
+                    k = g_nodes[n]
+                    if k.fn is None:
+                        raise ValueError(f"kernel {n} has no fn")
+                    if grp is None:
+                        grp = n_grp
+                        dev = self.ex.groups[grp]
+                    midx[n] = len(members)
+                    members.append(n)
+                    fns.append(k.fn)
+                    ops.append(k.op)
+                    costs.append(k.costs.get(grp, 0.0))
+                    entries.append(entry)
                 if grp is None:
-                    grp = n_grp
-                    dev = self.ex.groups[grp]
-                midx[n] = len(members)
-                members.append(n)
-                fns.append(k.fn)
-                ops.append(k.op)
-                costs.append(k.costs.get(grp, 0.0))
-                entries.append(entry)
-            if grp is None:
-                break
-            claimed.add(grp)
-            plans.append(
-                dict(
-                    grp=grp,
-                    dev=dev,
-                    members=members,
-                    midx=midx,
-                    fns=fns,
-                    ops=ops,
-                    costs=costs,
-                    entries=entries,
+                    break
+                claimed.add(grp)
+                plans.append(
+                    dict(
+                        grp=grp,
+                        dev=dev,
+                        members=members,
+                        midx=midx,
+                        fns=fns,
+                        ops=ops,
+                        costs=costs,
+                        entries=entries,
+                    )
                 )
-            )
         if not plans:
             return False
 
@@ -1120,7 +1155,11 @@ class ExecSession:
                     pl["keep"],
                 )
                 specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ext_args]
-                with jax.default_device(dev), warnings.catch_warnings():
+                with (
+                    self.spans(EXEC_COMPILE),
+                    jax.default_device(dev),
+                    warnings.catch_warnings(),
+                ):
                     warnings.filterwarnings("ignore", message=".*donated.*")
                     return (
                         jax.jit(chain, donate_argnums=donate).lower(*specs).compile()
@@ -1132,28 +1171,37 @@ class ExecSession:
             pl.update(fn=fn, hit=hit, ext_args=ext_args, donate=donate)
 
         wave_ms = 0.0
+        spans = self.spans
         if tk:
             # ONE host sync for the whole wave's externals, outside the
             # timed region (input production must not leak into the wall)
-            for pl in plans:
-                for a in pl["ext_args"]:
-                    if hasattr(a, "block_until_ready"):
-                        a.block_until_ready()
+            with spans(EXEC_WAIT):
+                for pl in plans:
+                    for a in pl["ext_args"]:
+                        if hasattr(a, "block_until_ready"):
+                            a.block_until_ready()
             t0 = time.perf_counter()
         for pl in plans:
-            if pl["donate"]:
-                with warnings.catch_warnings():
-                    warnings.filterwarnings("ignore", message=".*donated.*")
+            anchor = pl["members"][0]
+            req = g_nodes[anchor].meta.get("req", "")
+            with spans(EXEC_LAUNCH, kernel=anchor, req=req):
+                if pl["donate"]:
+                    with warnings.catch_warnings():
+                        warnings.filterwarnings("ignore", message=".*donated.*")
+                        pl["outs"] = pl["fn"](*pl["ext_args"])
+                else:
                     pl["outs"] = pl["fn"](*pl["ext_args"])
-            else:
-                pl["outs"] = pl["fn"](*pl["ext_args"])
         if tk:
             # the wave's single barrier
-            for pl in plans:
-                for o in pl["outs"]:
-                    if hasattr(o, "block_until_ready"):
-                        o.block_until_ready()
-            wave_ms = (time.perf_counter() - t0) * 1e3
+            with spans(EXEC_WAIT):
+                for pl in plans:
+                    for o in pl["outs"]:
+                        if hasattr(o, "block_until_ready"):
+                            o.block_until_ready()
+                t_ready = time.perf_counter()
+            wave_ms = (t_ready - t0) * 1e3
+        else:
+            t_ready = time.perf_counter()
 
         # retire: apportion the wave wall across ALL wave members by cost
         # weight (or read the cost clock), roll each chain's virtual times
@@ -1224,7 +1272,7 @@ class ExecSession:
                     buf_append(
                         KernelRun(
                             n, grp, kms, pl["per_nt"][i], pl["per_nb"][i],
-                            vstart, vfinish,
+                            vstart, vfinish, t_ready,
                         )
                     )
             self.per_group[grp] = self.per_group.get(grp, 0) + len(pl["members"])
@@ -1255,54 +1303,67 @@ class ExecSession:
         In fused mode a whole group-step executes at once (one compiled
         dispatch, one barrier) and its per-kernel records are replayed one
         per call, so online callers consume the same stepwise interface."""
-        if self.fused:
-            dispatch = self._fused_wave if self.async_groups else self._fused_superstep
-            if not self._fused_buf and not dispatch():
+        spans = self.spans
+        with spans(EXEC_ACCOUNT):
+            if self.fused:
+                dispatch = (
+                    self._fused_wave if self.async_groups else self._fused_superstep
+                )
+                if not self._fused_buf and not dispatch():
+                    return None
+                return self._fused_buf.pop(0)
+            with spans(EXEC_SELECT):
+                name = self.next_ready()
+            if name is None:
                 return None
-            return self._fused_buf.pop(0)
-        name = self.next_ready()
-        if name is None:
-            return None
-        k = self.g.nodes[name]
-        grp = self.assignment.get(name, self.host_group)
-        dev = self.ex.groups[grp]
-        args, nt, nb, ready_vt = self._gather(name, grp, dev)
-        self.n_transfers += nt
-        self.nbytes += nb
-        if k.fn is None:
-            raise ValueError(f"kernel {name} has no fn")
-        ms = 0.0
-        if self.time_kernels:
-            for a in args:
-                if hasattr(a, "block_until_ready"):
-                    a.block_until_ready()
-            t0 = time.perf_counter()
-        with jax.default_device(dev):
-            out = k.fn(*args)
-        if self.time_kernels:
-            if hasattr(out, "block_until_ready"):
-                out.block_until_ready()
-            ms = (time.perf_counter() - t0) * 1e3
-            self.kernel_ms[name] = ms
-        vstart = vfinish = 0.0
-        if self.comm is not None:
-            vstart = max(
-                self.group_free.get(grp, 0.0), ready_vt, self.earliest.get(name, 0.0)
-            )
-            vfinish = vstart + ms
-            if self._pending_channels:
-                vfinish = self._drain_channels(vstart, ms, vfinish)
-            self.group_free[grp] = vfinish
-            self.vnow = vfinish
-            self.vmax = max(self.vmax, vfinish)
-            self.vt_block[(name, grp)] = vfinish
-            self._block_window[name] = (vstart, vfinish)
-        self.valid[name] = {grp: out}
-        self.blocks[name] = out
-        self.per_group[grp] = self.per_group.get(grp, 0) + 1
-        self._done.add(name)
-        self._prefetch_ready()
-        return KernelRun(name, grp, ms, nt, nb, vstart, vfinish)
+            k = self.g.nodes[name]
+            grp = self.assignment.get(name, self.host_group)
+            dev = self.ex.groups[grp]
+            args, nt, nb, ready_vt = self._gather(name, grp, dev)
+            self.n_transfers += nt
+            self.nbytes += nb
+            if k.fn is None:
+                raise ValueError(f"kernel {name} has no fn")
+            ms = 0.0
+            if self.time_kernels:
+                with spans(EXEC_WAIT):
+                    for a in args:
+                        if hasattr(a, "block_until_ready"):
+                            a.block_until_ready()
+                t0 = time.perf_counter()
+            req = k.meta.get("req", "")
+            with spans(EXEC_LAUNCH, kernel=name, req=req), jax.default_device(dev):
+                out = k.fn(*args)
+            if self.time_kernels:
+                with spans(EXEC_WAIT):
+                    if hasattr(out, "block_until_ready"):
+                        out.block_until_ready()
+                    t_ready = time.perf_counter()
+                ms = (t_ready - t0) * 1e3
+                self.kernel_ms[name] = ms
+            else:
+                t_ready = time.perf_counter()
+            vstart = vfinish = 0.0
+            if self.comm is not None:
+                vstart = max(
+                    self.group_free.get(grp, 0.0),
+                    ready_vt,
+                    self.earliest.get(name, 0.0),
+                )
+                vfinish = vstart + ms
+                if self._pending_channels:
+                    vfinish = self._drain_channels(vstart, ms, vfinish)
+                self.group_free[grp] = vfinish
+                self.vnow = vfinish
+                self.vmax = max(self.vmax, vfinish)
+                self.vt_block[(name, grp)] = vfinish
+                self._block_window[name] = (vstart, vfinish)
+            self.valid[name] = {grp: out}
+            self.blocks[name] = out
+            self.per_group[grp] = self.per_group.get(grp, 0) + 1
+            self._done.add(name)
+            self._prefetch_ready()
+            return KernelRun(name, grp, ms, nt, nb, vstart, vfinish, t_ready)
 
     def run_all(self) -> None:
         if self.fused:
@@ -1319,8 +1380,9 @@ class ExecSession:
 
     def result(self) -> ExecResult:
         outs = {n: self.blocks[n] for n in self.g.exit_nodes() if n in self.blocks}
-        for a in outs.values():
-            a.block_until_ready()
+        with self.spans(EXEC_WAIT):
+            for a in outs.values():
+                a.block_until_ready()
         dt = (time.perf_counter() - self._t0) * 1e3
         return ExecResult(
             outputs=outs,
@@ -1384,6 +1446,7 @@ class JaxExecutor:
         stream_depth: int = 2,
         async_groups: bool = False,
         cost_clock: bool = False,
+        spans: Spans | None = None,
     ) -> ExecSession:
         return ExecSession(
             self,
@@ -1404,6 +1467,7 @@ class JaxExecutor:
             stream_depth=stream_depth,
             async_groups=async_groups,
             cost_clock=cost_clock,
+            spans=spans,
         )
 
     def run(

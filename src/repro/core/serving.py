@@ -50,6 +50,7 @@ the cache, so only a full-repartition escalation recompiles everything.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Callable, Mapping, Sequence
 
@@ -61,6 +62,8 @@ from .cost import Link, MeasuredCostModel
 from .executor import JaxExecutor, SuperStepCache, attach_request_kernels
 from .graph import TaskGraph
 from .simulate import Platform, WorkerAdd, WorkerDrop
+from .spans import (SERVE_ACCOUNT, SERVE_ADMIT, SERVE_ATTACH, SERVE_FEEDBACK,
+                    SERVE_PLAN, SERVE_PREPARE, Spans)
 from ..ft.elastic import Heartbeat, HeartbeatMonitor, feed_policy
 
 
@@ -108,6 +111,13 @@ class StepReport:
     n_donated: int = 0              # donated buffers the fused calls consumed
     # kernel -> the group its last execution ran on (kept out of to_dict)
     ran_on: dict = dataclasses.field(default_factory=dict, repr=False)
+    span_ms: dict = dataclasses.field(default_factory=dict)
+    #                               # span name -> self ms (repro.core.spans)
+    span_calls: dict = dataclasses.field(default_factory=dict)
+    #                               # span name -> spans closed
+    request_done_ms: dict = dataclasses.field(default_factory=dict)
+    #                               # request retired in the interval -> ms
+    #                               # from run_step entry to its last output
 
 
 @dataclasses.dataclass
@@ -176,7 +186,25 @@ class ServeReport:
             "stream_busy_ms": self.total("stream_busy_ms"),
             "waves": int(self.total("n_waves")),
             "overlap_ms": self.total("overlap_ms"),
+            "span_ms": self.span_ms(),
+            "request_p90_ms": self.request_p90_ms(),
         }
+
+    def span_ms(self) -> dict[str, float]:
+        """Self ms per span name, summed over the steps."""
+        out: dict[str, float] = {}
+        for s in self.steps:
+            for name, ms in s.span_ms.items():
+                out[name] = out.get(name, 0.0) + ms
+        return out
+
+    def request_p90_ms(self) -> float | None:
+        """Nearest-rank p90 of every request's completion time; None when
+        no request retired."""
+        done = sorted(ms for s in self.steps for ms in s.request_done_ms.values())
+        if not done:
+            return None
+        return done[max(0, math.ceil(0.9 * len(done)) - 1)]
 
 
 @dataclasses.dataclass
@@ -359,108 +387,121 @@ class ServingExecutor:
     def run_step(self, step: ArenaStep, policy, step_idx: int = 0
                  ) -> StepReport:
         wall0 = time.perf_counter()
-        g = step.graph.copy()
-        inputs = self.attach(g, self.side)
+        spans = Spans()
+        with spans(SERVE_PLAN):
+            g = step.graph.copy()
+            with spans(SERVE_ATTACH):
+                inputs = self.attach(g, self.side)
 
-        # split the revision: tasks whose arrival has passed vs gated chains
-        arrivals = dict(step.arrivals or {})
-        late_entries = {n: t for n, t in arrivals.items() if t > 0}
-        topo_idx = {n: i for i, n in enumerate(g.topo_order())}
-        arrival_of: dict[str, float] = {}
-        for root, t in late_entries.items():
-            for n in _downstream_of(g, [root]):
-                arrival_of[n] = max(arrival_of.get(n, 0.0), t)
-        gated = set(arrival_of)
+            # split the revision: tasks whose arrival has passed vs gated chains
+            arrivals = dict(step.arrivals or {})
+            late_entries = {n: t for n, t in arrivals.items() if t > 0}
+            topo_idx = {n: i for i, n in enumerate(g.topo_order())}
+            arrival_of: dict[str, float] = {}
+            for root, t in late_entries.items():
+                for n in _downstream_of(g, [root]):
+                    arrival_of[n] = max(arrival_of.get(n, 0.0), t)
+            gated = set(arrival_of)
 
-        # platform copy for this interval (events mutate it).  Unlike the
-        # simulator — which prepares on the full platform and THEN applies
-        # t<=0 events to demo the offline-restriction regime — a t<=0 event
-        # here edits the platform *before* prepare: in a live system a worker
-        # that left a previous interval is simply absent from this one.
-        platform = self.platform.copy()
-        events = sorted(step.events or (), key=lambda e: e.t_ms)
-        pre = [e for e in events if e.t_ms <= 0]
-        timed = [e for e in events if e.t_ms > 0]
+            # platform copy for this interval (events mutate it).  Unlike the
+            # simulator — which prepares on the full platform and THEN applies
+            # t<=0 events to demo the offline-restriction regime — a t<=0 event
+            # here edits the platform *before* prepare: in a live system a worker
+            # that left a previous interval is simply absent from this one.
+            platform = self.platform.copy()
+            events = sorted(step.events or (), key=lambda e: e.t_ms)
+            pre = [e for e in events if e.t_ms <= 0]
+            timed = [e for e in events if e.t_ms > 0]
 
-        state = _LiveState(g=g, platform=platform, finished=set())
-        for ev in pre:
-            if isinstance(ev, WorkerDrop):
-                platform.procs[:] = [p for p in platform.procs
-                                     if p.name != ev.proc]
-            elif isinstance(ev, WorkerAdd):
-                platform.procs.append(ev.proc)
+            state = _LiveState(g=g, platform=platform, finished=set())
+            for ev in pre:
+                if isinstance(ev, WorkerDrop):
+                    platform.procs[:] = [p for p in platform.procs
+                                         if p.name != ev.proc]
+                elif isinstance(ev, WorkerAdd):
+                    platform.procs.append(ev.proc)
 
-        # an online policy prepares on the *admitted* prefix and places the
-        # rest via admit_task as arrivals pass; a purely offline policy (no
-        # admit_task) would otherwise never place the late tasks, so it
-        # prepares on the full revision — the arrival gate still holds
-        # execution back, only the placement decision is made up front
-        admit_fn = getattr(policy, "admit_task", None)
-        if admit_fn is None:
-            prep_g = g
-        else:
-            admitted = [n for n in g.nodes if n not in gated]
-            prep_g = subgraph_of(g, admitted)
-        offline_ms = policy.prepare(prep_g, platform)
-        assignment = dict(getattr(policy, "assignment", {}))
-        for n in g.nodes:
-            if g.nodes[n].op != "source" and n not in assignment:
-                assignment[n] = self._fallback_class(g, n, platform)
+            # an online policy prepares on the *admitted* prefix and places the
+            # rest via admit_task as arrivals pass; a purely offline policy (no
+            # admit_task) would otherwise never place the late tasks, so it
+            # prepares on the full revision — the arrival gate still holds
+            # execution back, only the placement decision is made up front
+            admit_fn = getattr(policy, "admit_task", None)
+            if admit_fn is None:
+                prep_g = g
+            else:
+                admitted = [n for n in g.nodes if n not in gated]
+                prep_g = subgraph_of(g, admitted)
+            with spans(SERVE_PREPARE):
+                offline_ms = policy.prepare(prep_g, platform)
+            assignment = dict(getattr(policy, "assignment", {}))
+            for n in g.nodes:
+                if g.nodes[n].op != "source" and n not in assignment:
+                    assignment[n] = self._fallback_class(g, n, platform)
 
-        # the shared communication model: transfers charged to the actual
-        # src-node -> dst-node lanes, overlapped with compute on the session's
-        # two-resource virtual timeline (same engine the simulator runs)
-        comm = CommEngine(platform.topo)
-        group_nodes = {cls: platform.node_of_class(cls)
-                       for cls in platform.classes}
-        for cls in self.executor.groups:
-            group_nodes.setdefault(cls, platform.host_node)
-        session = self.executor.session(
-            g, assignment, inputs, host_group=self.host_group,
-            time_kernels=True, gated=gated, comm=comm,
-            group_nodes=group_nodes, fused=self.fused,
-            cache=self.superstep_cache,
-            revision=int(getattr(policy, "revision", 0)),
-            streaming=self.streaming, chunk_bytes=self.chunk_bytes,
-            stream_depth=self.stream_depth, async_groups=self.async_groups)
+            # the shared communication model: transfers charged to the actual
+            # src-node -> dst-node lanes, overlapped with compute on the session's
+            # two-resource virtual timeline (same engine the simulator runs)
+            comm = CommEngine(platform.topo)
+            group_nodes = {cls: platform.node_of_class(cls)
+                           for cls in platform.classes}
+            for cls in self.executor.groups:
+                group_nodes.setdefault(cls, platform.host_node)
+            session = self.executor.session(
+                g, assignment, inputs, host_group=self.host_group,
+                time_kernels=True, gated=gated, comm=comm,
+                group_nodes=group_nodes, fused=self.fused,
+                cache=self.superstep_cache,
+                revision=int(getattr(policy, "revision", 0)),
+                streaming=self.streaming, chunk_bytes=self.chunk_bytes,
+                stream_depth=self.stream_depth,
+                async_groups=self.async_groups, spans=spans)
 
-        clock = 0.0
-        decision_ms = 0.0
-        admitted_late = redispatched = 0
-        spills = 0
-        dropped: list[str] = []
-        added: list[str] = []
-        cls_ms: dict[str, list[float]] = {}
-        ran_on: dict[str, str] = {}
-        peak_mem: dict[str, float] = {}
-        # request-granular KV lifetime: a chain's footprint frees when its
-        # whole request has executed (meta["req"], as in the simulator)
-        req_tasks: dict[str, list[str]] = {}
-        for n, k in g.nodes.items():
-            r = k.meta.get("req")
-            if r is not None:
-                req_tasks.setdefault(r, []).append(n)
-        req_left = {r: len(v) for r, v in req_tasks.items()}
-        pending_events = list(timed)
-        pending_admits = sorted(arrival_of.items(), key=lambda kv: (kv[1], kv[0]))
+            clock = 0.0
+            decision_ms = 0.0
+            admitted_late = redispatched = 0
+            spills = 0
+            dropped: list[str] = []
+            added: list[str] = []
+            cls_ms: dict[str, list[float]] = {}
+            ran_on: dict[str, str] = {}
+            peak_mem: dict[str, float] = {}
+            # request-granular KV lifetime: a chain's footprint frees when its
+            # whole request has executed (meta["req"], as in the simulator);
+            # the same moment is the request's completion time
+            req_tasks: dict[str, list[str]] = {}
+            for n, k in g.nodes.items():
+                r = k.meta.get("req")
+                if r is not None:
+                    req_tasks.setdefault(r, []).append(n)
+            req_left = {r: len(v) for r, v in req_tasks.items()}
+            req_done_ms: dict[str, float] = {}
+            pending_events = list(timed)
+            pending_admits = sorted(arrival_of.items(), key=lambda kv: (kv[1], kv[0]))
 
         def fire_due():
             nonlocal decision_ms, redispatched, admitted_late
             nonlocal pending_events, pending_admits
-            while pending_events and pending_events[0].t_ms <= clock + 1e-12:
-                ev = pending_events.pop(0)
-                if isinstance(ev, WorkerDrop):
-                    oh, rd = self._apply_drop(ev.proc, state, session,
-                                              policy)
-                    decision_ms += oh
-                    redispatched += rd
-                    dropped.append(ev.proc)
-                elif isinstance(ev, WorkerAdd):
-                    decision_ms += self._apply_add(ev.proc, state, session,
-                                                   policy)
-                    added.append(ev.proc.name)
-            due = [n for n, t in pending_admits if t <= clock + 1e-12]
-            if due:
+            due_at = clock + 1e-12
+            if not ((pending_events and pending_events[0].t_ms <= due_at)
+                    or (pending_admits and pending_admits[0][1] <= due_at)):
+                return
+            with spans(SERVE_ADMIT):
+                while pending_events and pending_events[0].t_ms <= due_at:
+                    ev = pending_events.pop(0)
+                    if isinstance(ev, WorkerDrop):
+                        oh, rd = self._apply_drop(ev.proc, state, session,
+                                                  policy)
+                        decision_ms += oh
+                        redispatched += rd
+                        dropped.append(ev.proc)
+                    elif isinstance(ev, WorkerAdd):
+                        decision_ms += self._apply_add(ev.proc, state, session,
+                                                       policy)
+                        added.append(ev.proc.name)
+                due = [n for n, t in pending_admits if t <= due_at]
+                if not due:
+                    return
                 done = set(due)
                 pending_admits = [(n, t) for n, t in pending_admits
                                   if n not in done]
@@ -478,102 +519,112 @@ class ServingExecutor:
                     session.reassign(dict(policy.assignment))
                 session.admit(due, at=clock)
 
-        fire_due()
-        while True:
-            run = session.step()
-            if run is None:
-                if session.done():
-                    break
-                future = [t for _, t in pending_admits]
-                future += [e.t_ms for e in pending_events]
-                if not future:
-                    raise RuntimeError(
-                        f"serving deadlock: pending {session.pending()!r}")
-                clock = max(clock, min(future))
-                fire_due()
-                continue
-            # close the measurement loop: observed wall time -> cost history;
-            # the stream clock follows the session's two-resource timeline
-            # (compute overlapped with lane transfers), not a serialized sum
-            clock = max(clock, run.t_finish)
-            ran_on[run.name] = run.group
-            first = run.name not in state.finished
-            state.finished.add(run.name)
-            kern = g.nodes[run.name]
-            r = kern.meta.get("req")
-            req_live = r is None or req_left.get(r, 0) > 0
-            # residency: add once per live block — a kernel re-executed after
-            # a group eviction re-homes its KV (its old entry was cleared
-            # with the dead group), but a block already accounted or whose
-            # request has retired must not inflate the ledger
-            if kern.mem_bytes and run.name not in state.task_group and req_live:
-                state.resident[run.group] = (state.resident.get(run.group, 0.0)
-                                             + kern.mem_bytes)
-                state.task_group[run.name] = run.group
-                peak_mem[run.group] = max(peak_mem.get(run.group, 0.0),
-                                          state.resident[run.group])
-                if (state.resident[run.group]
-                        > platform.mem_cap_of(run.group) + 1e-6):
-                    spills += 1
-            if first and r is not None and r in req_left:
-                req_left[r] -= 1
-                if req_left[r] == 0:  # request retired: free its KV
-                    for n in req_tasks[r]:
-                        grp = state.task_group.pop(n, None)
-                        if grp is not None:
-                            state.resident[grp] -= g.nodes[n].mem_bytes
-            op = kern.op
-            self.cost_model.observe(op, self.side, run.group, run.ms)
-            cls_ms.setdefault(run.group, []).append(run.ms)
+        with spans(SERVE_ACCOUNT):
             fire_due()
+            while True:
+                run = session.step()
+                if run is None:
+                    if session.done():
+                        break
+                    future = [t for _, t in pending_admits]
+                    future += [e.t_ms for e in pending_events]
+                    if not future:
+                        raise RuntimeError(
+                            f"serving deadlock: pending {session.pending()!r}")
+                    clock = max(clock, min(future))
+                    fire_due()
+                    continue
+                # close the measurement loop: observed wall time -> cost history;
+                # the stream clock follows the session's two-resource timeline
+                # (compute overlapped with lane transfers), not a serialized sum
+                clock = max(clock, run.t_finish)
+                ran_on[run.name] = run.group
+                first = run.name not in state.finished
+                state.finished.add(run.name)
+                kern = g.nodes[run.name]
+                r = kern.meta.get("req")
+                req_live = r is None or req_left.get(r, 0) > 0
+                # residency: add once per live block — a kernel re-executed after
+                # a group eviction re-homes its KV (its old entry was cleared
+                # with the dead group), but a block already accounted or whose
+                # request has retired must not inflate the ledger
+                if kern.mem_bytes and run.name not in state.task_group and req_live:
+                    state.resident[run.group] = (state.resident.get(run.group, 0.0)
+                                                 + kern.mem_bytes)
+                    state.task_group[run.name] = run.group
+                    peak_mem[run.group] = max(peak_mem.get(run.group, 0.0),
+                                              state.resident[run.group])
+                    if (state.resident[run.group]
+                            > platform.mem_cap_of(run.group) + 1e-6):
+                        spills += 1
+                if first and r is not None and r in req_left:
+                    req_left[r] -= 1
+                    if req_left[r] == 0:  # request retired: free its KV
+                        req_done_ms[r] = (run.t_ready - wall0) * 1e3
+                        for n in req_tasks[r]:
+                            grp = state.task_group.pop(n, None)
+                            if grp is not None:
+                                state.resident[grp] -= g.nodes[n].mem_bytes
+                op = kern.op
+                self.cost_model.observe(op, self.side, run.group, run.ms)
+                cls_ms.setdefault(run.group, []).append(run.ms)
+                fire_due()
 
-        # heartbeat per class for this interval; EWMAs feed the policy's
-        # live-cost view so the *next* prepare is straggler-aware
-        t_wall = time.time()
-        for cls, samples in cls_ms.items():
-            self.monitor.report(Heartbeat(group=cls, step=step_idx,
-                                          step_time_ms=sum(samples)
-                                          / len(samples), t_wall=t_wall))
-        if hasattr(policy, "observe_step_ms"):
-            feed_policy(policy, self.monitor)
+        with spans(SERVE_FEEDBACK):
+            # heartbeat per class for this interval; EWMAs feed the policy's
+            # live-cost view so the *next* prepare is straggler-aware
+            t_wall = time.time()
+            for cls, samples in cls_ms.items():
+                self.monitor.report(Heartbeat(group=cls, step=step_idx,
+                                              step_time_ms=sum(samples)
+                                              / len(samples), t_wall=t_wall))
+            if hasattr(policy, "observe_step_ms"):
+                feed_policy(policy, self.monitor)
+            outputs = (session.result().outputs if self.check is not None
+                       else None)
 
-        report = StepReport(
-            tag=step.tag,
-            n_kernels=sum(session.per_group.values()),
-            makespan_ms=max(clock, session.vmax),
-            wall_ms=(time.perf_counter() - wall0) * 1e3,
-            n_transfers=session.n_transfers,
-            bytes_transferred=session.nbytes,
-            offline_ms=offline_ms,
-            decision_ms=decision_ms,
-            admitted_late=admitted_late,
-            redispatched=redispatched,
-            reexecuted=len(session.reexecuted),
-            kernel_ms_by_class={c: sum(v) / len(v) for c, v in cls_ms.items()},
-            dropped=dropped,
-            added=added,
-            events_missed=list(pending_events),
-            spills=spills,
-            peak_mem_bytes=peak_mem,
-            transfer_busy_ms=comm.busy_ms,
-            lane_busy_ms=comm.lane_busy_ms(),
-            n_prefetched=comm.n_prefetched,
-            tier_busy_ms=comm.tier_busy_ms(),
-            n_throttled=comm.n_throttled,
-            n_preempted=comm.n_preempted,
-            fused_steps=session.fused_steps,
-            cache_hits=session.cache_hits,
-            cache_misses=session.cache_misses,
-            n_streamed=comm.n_streamed,
-            n_stalled_chunks=comm.n_stalled_chunks,
-            stream_busy_ms=comm.stream_busy_ms,
-            n_waves=session.n_waves,
-            overlap_ms=session.overlap_ms,
-            n_donated=session.n_donated,
-            ran_on=ran_on,
-        )
+            report = StepReport(
+                tag=step.tag,
+                n_kernels=sum(session.per_group.values()),
+                makespan_ms=max(clock, session.vmax),
+                wall_ms=0.0,
+                n_transfers=session.n_transfers,
+                bytes_transferred=session.nbytes,
+                offline_ms=offline_ms,
+                decision_ms=decision_ms,
+                admitted_late=admitted_late,
+                redispatched=redispatched,
+                reexecuted=len(session.reexecuted),
+                kernel_ms_by_class={c: sum(v) / len(v) for c, v in cls_ms.items()},
+                dropped=dropped,
+                added=added,
+                events_missed=list(pending_events),
+                spills=spills,
+                peak_mem_bytes=peak_mem,
+                transfer_busy_ms=comm.busy_ms,
+                lane_busy_ms=comm.lane_busy_ms(),
+                n_prefetched=comm.n_prefetched,
+                tier_busy_ms=comm.tier_busy_ms(),
+                n_throttled=comm.n_throttled,
+                n_preempted=comm.n_preempted,
+                fused_steps=session.fused_steps,
+                cache_hits=session.cache_hits,
+                cache_misses=session.cache_misses,
+                n_streamed=comm.n_streamed,
+                n_stalled_chunks=comm.n_stalled_chunks,
+                stream_busy_ms=comm.stream_busy_ms,
+                n_waves=session.n_waves,
+                overlap_ms=session.overlap_ms,
+                n_donated=session.n_donated,
+                ran_on=ran_on,
+                request_done_ms=req_done_ms,
+            )
+        # every span has closed: their self times partition the wall time
+        report.wall_ms = (time.perf_counter() - wall0) * 1e3
+        report.span_ms = spans.ms
+        report.span_calls = spans.calls
         if self.check is not None:
-            self.check(step, report, session.result().outputs)
+            self.check(step, report, outputs)
         return report
 
     # -- whole stream ----------------------------------------------------------
@@ -643,6 +694,9 @@ def merge_serve_reports(reports: Sequence[ServeReport],
         peaks: dict[str, float] = {}
         lanes: dict[str, float] = {}
         tiers: dict[str, float] = {}
+        span_ms: dict[str, float] = {}
+        span_calls: dict[str, int] = {}
+        done_ms: dict[str, float] = {}
         for s in group:
             for cls, ms in s.kernel_ms_by_class.items():
                 classes.setdefault(cls, []).append(ms)
@@ -652,6 +706,11 @@ def merge_serve_reports(reports: Sequence[ServeReport],
                 lanes[lane] = lanes.get(lane, 0.0) + ms
             for tier, ms in s.tier_busy_ms.items():
                 tiers[tier] = tiers.get(tier, 0.0) + ms
+            for name, ms in s.span_ms.items():
+                span_ms[name] = span_ms.get(name, 0.0) + ms
+            for name, n in s.span_calls.items():
+                span_calls[name] = span_calls.get(name, 0) + n
+            done_ms.update(s.request_done_ms)
 
         def tot(field: str):
             return sum(getattr(s, field) for s in group)
@@ -688,5 +747,8 @@ def merge_serve_reports(reports: Sequence[ServeReport],
             stream_busy_ms=tot("stream_busy_ms"),
             n_waves=int(tot("n_waves")),
             overlap_ms=tot("overlap_ms"),
+            span_ms=span_ms,
+            span_calls=span_calls,
+            request_done_ms=done_ms,
         ))
     return merged

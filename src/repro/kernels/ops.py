@@ -109,20 +109,21 @@ def build_chain(steps, keep=None):
     through them instead of materializing one buffer per kernel, which is
     most of the super-step's dispatch-overhead win.
 
-    The returned ``chain(*ext) -> tuple(kept outputs)`` is pure and
+    The returned ``superstep(*ext) -> tuple(kept outputs)`` is pure and
     jit-friendly: the executor jits it once per (revision, group signature,
     shapes/dtypes) with dead external buffers donated, so a whole partition
     group runs as a single XLA computation — one async dispatch and one
-    ready-barrier per group-step instead of one per kernel.
+    ready-barrier per group-step instead of one per kernel.  Its name is
+    the compiled module's (``jit_superstep``), which a profiler trace shows.
     """
     plan = [(fn, tuple(srcs)) for fn, srcs in steps]
     keep = tuple(range(len(plan))) if keep is None else tuple(keep)
 
-    def chain(*ext):
+    def superstep(*ext):
         outs = []
         for fn, srcs in plan:
             args = [ext[i] if kind == "ext" else outs[i] for kind, i in srcs]
             outs.append(fn(*args))
         return tuple(outs[i] for i in keep)
 
-    return chain
+    return superstep
