@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels.matmul import (MAX_BLOCKS, VMEM_LIMIT_BYTES, choose_blocks,
+                                  vmem_bytes)
 from repro.kernels.matmul import matmul as pl_matmul
 from repro.kernels.matadd import matadd as pl_matadd
 from repro.kernels.flash_attention import flash_attention as pl_flash
@@ -18,8 +20,9 @@ def _tol(dtype):
         dict(rtol=2e-4, atol=2e-4)
 
 
+# (M, K, N); the last runs the chosen blocks on a (2, 2, 2) grid
 @pytest.mark.parametrize("shape", [(128, 128, 128), (256, 384, 128),
-                                   (128, 256, 512)])
+                                   (128, 256, 512), (2048, 1024, 2048)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_matmul_sweep(shape, dtype):
     M, K, N = shape
@@ -29,6 +32,32 @@ def test_matmul_sweep(shape, dtype):
     expect = ref.matmul(a, b)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("M, N, K, itemsize", [
+    (128, 128, 128, 4), (256, 128, 384, 4), (2048, 2048, 2048, 4),
+    (2048, 2048, 1024, 4), (2048, 2048, 2048, 2), (1024, 128, 2048, 4),
+    (384, 640, 1152, 4), (128, 4096, 128, 4), (4096, 4096, 4096, 8)])
+def test_choose_blocks(M, N, K, itemsize):
+    """Multiples of 128 that divide the dims, no larger than ``MAX_BLOCKS``,
+    whose pipelined tiles fit the VMEM limit the kernel sets."""
+    blocks = choose_blocks(M, N, K, itemsize)
+    for dim, b, cap in zip((M, N, K), blocks, MAX_BLOCKS):
+        assert b % 128 == 0 and dim % b == 0 and b <= cap
+    assert vmem_bytes(*blocks, itemsize) <= VMEM_LIMIT_BYTES
+    if itemsize <= 4:  # nothing shrinks below the largest divisor
+        assert all(b == max(d for d in range(128, min(dim, cap) + 1, 128)
+                            if dim % d == 0)
+                   for dim, b, cap in zip((M, N, K), blocks, MAX_BLOCKS))
+
+
+@pytest.mark.parametrize("M, N, K, itemsize, expect", [
+    (128, 128, 128, 4, (128, 128, 128)),  # a 128-sided problem keeps 128^3
+    (2048, 2048, 2048, 4, (1024, 1024, 512)),  # the fastest timed on a v5e
+    (2048, 2048, 1024, 4, (1024, 1024, 512)),  # test_matmul_sweep's (2, 2, 2) grid
+    (4096, 4096, 4096, 8, (512, 1024, 512))])  # 8-byte blocks overflow: shrink
+def test_choose_blocks_picks(M, N, K, itemsize, expect):
+    assert choose_blocks(M, N, K, itemsize) == expect
 
 
 @pytest.mark.parametrize("shape", [(256, 256), (512, 384), (64, 128)])
@@ -157,3 +186,16 @@ def test_ops_matadd_padded_pallas_matches_oracle(monkeypatch, side):
     b = jax.random.normal(jax.random.PRNGKey(1), (side, side))
     np.testing.assert_array_equal(np.asarray(ops.matadd(a, b)),
                                   np.asarray(a + b))
+
+
+def test_ops_matmul_padded_pallas_matches_oracle(monkeypatch):
+    """An unaligned side pads to 1024, where the chosen blocks split K."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "KERNEL_MODE", "pallas")
+    a = jax.random.normal(jax.random.PRNGKey(0), (1000, 1000))
+    b = jax.random.normal(jax.random.PRNGKey(1), (1000, 1000))
+    out = ops.matmul(a, b)
+    assert out.shape == (1000, 1000)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref.matmul(a, b)),
+                               **_tol(jnp.float32))

@@ -8,6 +8,7 @@ The topology is described inside a module fixture, never at import: only
 the worker that runs this file may load the TPU library."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,20 @@ def _assert_kernel(compiled):
 def test_matmul_compiles_for_v5e(one_chip, dtype):
     sq = ((SIDE, SIDE), dtype)
     _assert_kernel(_compile(matmul, [sq, sq], one_chip))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_matmul_is_one_kernel_named_matmul(one_chip, dtype):
+    """With the blocks it chooses at 2048, the program is the Pallas call
+    alone (no pad, cast or copy beside it), and the op keeps the name
+    ``matmul.<n>`` that the benchmark's roofline reader matches."""
+    sq = ((SIDE, SIDE), dtype)
+    text = _compile(matmul, [sq, sq], one_chip).as_text()
+    entry = text[text.index("\nENTRY "):]
+    ops_ = re.findall(r"^\s+(?:ROOT )?%(\S+) = (.*)$", entry, re.M)
+    work = [name for name, rest in ops_ if "parameter(" not in rest]
+    assert len(work) == 1 and re.fullmatch(r"matmul\.\d+", work[0])
+    assert 'custom_call_target="tpu_custom_call"' in dict(ops_)[work[0]]
 
 
 def test_matadd_compiles_for_v5e(one_chip):
