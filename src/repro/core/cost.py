@@ -101,6 +101,23 @@ PAPER_EFFICIENCY = {
     ("gpu", "matadd"): 0.125,
     ("cpu", "matmul"): 0.8,
     ("gpu", "matmul"): 0.6,
+    # The tile Cholesky's kernels (graph.tile_cholesky_dag), as StarPU's
+    # Cholesky runs them on a multicore + Kepler node: the BLAS-3 updates
+    # near the matmul's rates; syrk and trsm a step below (half the product
+    # or a triangular dependency chain); potrf's panel factorization a
+    # latency-bound chain that a GPU runs far below its peak, which is why
+    # StarPU/MAGMA keep it on the CPU; diag_shift an elementwise pass like
+    # matadd.
+    ("cpu", "gemm"): 0.8,
+    ("gpu", "gemm"): 0.6,
+    ("cpu", "syrk"): 0.7,
+    ("gpu", "syrk"): 0.5,
+    ("cpu", "trsm"): 0.6,
+    ("gpu", "trsm"): 0.3,
+    ("cpu", "potrf"): 0.5,
+    ("gpu", "potrf"): 0.05,
+    ("cpu", "diag_shift"): 0.5,
+    ("gpu", "diag_shift"): 0.125,
 }
 
 
@@ -114,11 +131,23 @@ def paper_calibrated_model() -> "AnalyticCostModel":
 # ---------------------------------------------------------------------------
 
 def kernel_flops_bytes(op: str, n: int, dtype_bytes: int = 4) -> tuple[float, float]:
-    """FLOPs and HBM bytes for the paper's square-matrix kernels of side n."""
+    """FLOPs and HBM bytes for the square-matrix kernels of side n: the
+    paper's MM/MA and the tile Cholesky's five (each tile read or written
+    once)."""
     if op == "matmul":
         return 2.0 * n ** 3, 3.0 * n * n * dtype_bytes
     if op == "matadd":
         return 1.0 * n * n, 3.0 * n * n * dtype_bytes
+    if op == "diag_shift":
+        return 1.0 * n * n, 2.0 * n * n * dtype_bytes
+    if op == "potrf":
+        return n ** 3 / 3.0, 2.0 * n * n * dtype_bytes
+    if op == "trsm":
+        return 1.0 * n ** 3, 3.0 * n * n * dtype_bytes
+    if op == "syrk":
+        return 1.0 * n * n * (n + 1), 3.0 * n * n * dtype_bytes
+    if op == "gemm":
+        return 2.0 * n ** 3, 4.0 * n * n * dtype_bytes
     raise KeyError(f"unknown analytic op {op!r}")
 
 

@@ -79,6 +79,7 @@ device sets.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Iterable, Mapping
@@ -829,7 +830,7 @@ class ExecSession:
             t0 = time.perf_counter()
         anchor = members[0]
         req = g_nodes[anchor].meta.get("req", "")
-        with spans(EXEC_LAUNCH, kernel=anchor, req=req):
+        with spans(EXEC_LAUNCH, kernel=anchor, op="superstep", req=req):
             if donate:
                 with warnings.catch_warnings():
                     warnings.filterwarnings("ignore", message=".*donated.*")
@@ -1184,7 +1185,7 @@ class ExecSession:
         for pl in plans:
             anchor = pl["members"][0]
             req = g_nodes[anchor].meta.get("req", "")
-            with spans(EXEC_LAUNCH, kernel=anchor, req=req):
+            with spans(EXEC_LAUNCH, kernel=anchor, op="superstep", req=req):
                 if pl["donate"]:
                     with warnings.catch_warnings():
                         warnings.filterwarnings("ignore", message=".*donated.*")
@@ -1332,7 +1333,10 @@ class ExecSession:
                             a.block_until_ready()
                 t0 = time.perf_counter()
             req = k.meta.get("req", "")
-            with spans(EXEC_LAUNCH, kernel=name, req=req), jax.default_device(dev):
+            with (
+                spans(EXEC_LAUNCH, kernel=name, op=k.op, req=req),
+                jax.default_device(dev),
+            ):
                 out = k.fn(*args)
             if self.time_kernels:
                 with spans(EXEC_WAIT):
@@ -1497,13 +1501,11 @@ class JaxExecutor:
         return s.result()
 
 
-def _attach_kernels(g, n: int, fns: Mapping, dtype: str, seed: int) -> dict:
+def _bind_kernels(g, fns: Mapping) -> list[str]:
     """Attach real implementations from ``fns`` (op -> callable) to every
-    kernel and seed a ``<kernel>/in`` host input block for each entry kernel
-    (one fed by the virtual source, or one with no predecessors at all).
-    Returns the inputs dict for :meth:`JaxExecutor.run`."""
-    key = jax.random.PRNGKey(seed)
-    inputs = {}
+    kernel; returns the entry kernels (one fed by the virtual source, or
+    one with no predecessors at all), which read a ``<kernel>/in`` block."""
+    entries = []
     for name, k in g.nodes.items():
         if k.op == "source":
             continue
@@ -1515,9 +1517,34 @@ def _attach_kernels(g, n: int, fns: Mapping, dtype: str, seed: int) -> dict:
         k.fn = fns[k.op]
         preds = g.predecessors(name)
         if not preds or any(g.nodes[p].op == "source" for p in preds):
-            key, sub = jax.random.split(key)
-            inputs[name + "/in"] = jax.random.normal(sub, (n, n), dtype=dtype)
+            entries.append(name)
+    return entries
+
+
+def _attach_kernels(g, n: int, fns: Mapping, dtype: str, seed: int) -> dict:
+    """:func:`_bind_kernels`, and a standard normal ``<kernel>/in`` host
+    input block for each entry kernel.  Returns the inputs dict for
+    :meth:`JaxExecutor.run`."""
+    key = jax.random.PRNGKey(seed)
+    inputs = {}
+    for name in _bind_kernels(g, fns):
+        key, sub = jax.random.split(key)
+        inputs[name + "/in"] = jax.random.normal(sub, (n, n), dtype=dtype)
     return inputs
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_drawer(count: int, n: int, dtype: str, scale: float):
+    """One jitted call that draws ``count`` normal (n, n) tiles times
+    ``scale`` (its module is ``jit_draw_tiles``)."""
+
+    def draw_tiles(key):
+        keys = jax.random.split(key, count)
+        return tuple(
+            jax.random.normal(keys[i], (n, n), dtype) * scale for i in range(count)
+        )
+
+    return jax.jit(draw_tiles)
 
 
 def attach_matrix_kernels(g, n: int, dtype="float32") -> dict:
@@ -1543,3 +1570,31 @@ def attach_request_kernels(g, n: int, dtype="float32") -> dict:
         "decode": lambda *xs: ops.matadd(xs[0], xs[1] if len(xs) > 1 else xs[0]),
     }
     return _attach_kernels(g, n, fns, dtype, seed=1)
+
+
+def attach_tile_kernels(g, n: int, dtype="float32") -> dict:
+    """The tile Cholesky's kernels (:func:`repro.core.graph.tile_cholesky_dag`)
+    as real fns: ``gemm`` is ``ops.gemm_nt`` (C - A B^T), ``syrk`` the same
+    with B = A, ``potrf``/``trsm`` XLA's Cholesky and triangular solve, and
+    ``diag_shift`` at its kernel's ``meta["shift"]``.  Each tile a task
+    reads first from the source is seeded at scale 1/sqrt(N), N the matrix
+    order, so that the symmetric random part's spectrum lies in about
+    [-2, 2] and the shift sets the least eigenvalue.  The tiles are drawn
+    in one jitted call."""
+    from ..kernels import ops
+
+    fns = {
+        "diag_shift": ops.diag_shift,
+        "potrf": ops.potrf,
+        "trsm": ops.trsm,
+        "syrk": lambda c, a: ops.gemm_nt(c, a, a),
+        "gemm": ops.gemm_nt,
+    }
+    entries = _bind_kernels(g, fns)
+    for k in g.nodes.values():
+        if k.op == "diag_shift":
+            k.fn = functools.partial(ops.diag_shift, shift=k.meta["shift"])
+    tiles = sum(k.op == "diag_shift" for k in g.nodes.values())
+    draw = _tile_drawer(len(entries), n, str(dtype), (n * tiles) ** -0.5)
+    blocks = draw(jax.random.PRNGKey(2))
+    return {name + "/in": b for name, b in zip(entries, blocks)}

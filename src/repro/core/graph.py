@@ -288,3 +288,53 @@ def resolve_edge_bytes(g: TaskGraph) -> None:
         else:
             base = g.nodes[e.src].out_bytes
         g._edges[(e.src, e.dst)] = _dc.replace(e, nbytes=e.blocks * base)
+
+
+# ---------------------------------------------------------------------------
+# Tile Cholesky DAG (PLASMA's pdpotrf: lower, right-looking; Buttari et al.,
+# Parallel Computing 35(1), 2009), the task graph StarPU/Chameleon and XKaapi
+# schedule on CPU+GPU platforms.
+# ---------------------------------------------------------------------------
+
+def tile_cholesky_dag(tiles: int, *, shift: float) -> TaskGraph:
+    """The factorization A = L L^T of a ``tiles`` x ``tiles`` matrix of
+    square tiles, one kernel per tile operation, each writing a new version
+    of its tile and reading the newest versions of its inputs (in order):
+
+    * ``diag_shift_i``: tril(X) + tril(X, -1)^T + shift * I from the seeded
+      tile X at (i, i), so that A = sym(R) + shift * I for seeded R;
+    * ``potrf_k``: L_kk = chol(A_kk);
+    * ``trsm_i_k``: L_ik = A_ik L_kk^-T (A_ik, L_kk), i > k;
+    * ``syrk_i_k``: A_ii - L_ik L_ik^T (A_ii, L_ik), i > k;
+    * ``gemm_i_j_k``: A_ij - L_ik L_jk^T (A_ij, L_ik, L_jk), i > j > k.
+
+    A tile below the diagonal that no task has written yet is the seeded
+    input of its first reader (an arrow from the virtual source).  The one
+    exit is ``potrf_<tiles-1>``, which every task reaches.  ``meta["tile"]``
+    is the (row, column) a kernel writes and ``meta["step"]`` its k."""
+    g = TaskGraph()
+    g.add_kernel(Kernel(name=SOURCE, op="source", costs={}))
+    newest: dict[tuple[int, int], str] = {}  # tile -> the kernel of its newest version
+
+    def task(name: str, op: str, tile: tuple[int, int], step: int,
+             args: list[str | None], **meta) -> None:
+        g.add(name, op=op, meta={"tile": tile, "step": step, **meta})
+        for a in args:
+            g.add_edge(SOURCE if a is None else a, name, blocks=1)
+        newest[tile] = name
+
+    for i in range(tiles):
+        task(f"diag_shift_{i}", "diag_shift", (i, i), -1, [None], shift=shift)
+    for k in range(tiles):
+        task(f"potrf_{k}", "potrf", (k, k), k, [newest[(k, k)]])
+        for i in range(k + 1, tiles):
+            task(f"trsm_{i}_{k}", "trsm", (i, k), k,
+                 [newest.get((i, k)), f"potrf_{k}"])
+        for i in range(k + 1, tiles):
+            task(f"syrk_{i}_{k}", "syrk", (i, i), k,
+                 [newest[(i, i)], f"trsm_{i}_{k}"])
+            for j in range(k + 1, i):
+                task(f"gemm_{i}_{j}_{k}", "gemm", (i, j), k,
+                     [newest.get((i, j)), f"trsm_{i}_{k}", f"trsm_{j}_{k}"])
+    g.validate()
+    return g

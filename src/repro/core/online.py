@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import statistics
 import time
 from typing import Callable, Mapping, Sequence
 
@@ -661,11 +662,16 @@ class IncrementalGpPolicy(GpPolicy):
         capped by free memory.
 
         Each class with a live observation has its static share scaled by
-        (cost-table mean kernel ms / observed ms), then the vector is
-        renormalized.  Unmeasured classes keep their static share, so with no
-        feedback this is exactly :meth:`targets_for` (the paper's offline
-        formula); with feedback, a straggling class's target shrinks in
-        proportion to how much slower it *actually* runs than the table says.
+        (cost-table median kernel ms / observed ms), then the vector is
+        renormalized.  The observation is a median kernel ms too (the
+        executor's heartbeats), and the table median is over the kernels the
+        class was last given, the ones it timed (every kernel it can run, if
+        it was given none): on a graph of mixed ops the class's op mix then
+        cancels out of the ratio.  Unmeasured classes keep their static
+        share, so with no feedback this is exactly :meth:`targets_for` (the
+        paper's offline formula); with feedback, a straggling class's target
+        shrinks in proportion to how much slower it *actually* runs than the
+        table says.
 
         On a capacity-declaring platform the result is then passed through
         :meth:`_cap_targets_by_memory`: a class cannot be asked to hold a
@@ -676,14 +682,19 @@ class IncrementalGpPolicy(GpPolicy):
         if self.targets_override:
             return targets
         if self.live_step_ms:
-            kernels = [k for k in g.nodes.values() if k.op != "source"]
+            p = self.partitioner
+            ran, placed = (p.g, p.assignment) if p is not None else (g, {})
+            kernels = [(n, k) for n, k in ran.nodes.items() if k.op != "source"]
             scaled: dict[str, float] = {}
             for c, t in targets.items():
                 ratio = 1.0
                 live = self.live_step_ms.get(c, 0.0)
                 if live > 0 and kernels:
-                    costs = [k.costs[c] for k in kernels if c in k.costs]
-                    table = sum(costs) / len(costs) if costs else 0.0
+                    able = [(n, k.costs[c]) for n, k in kernels if c in k.costs]
+                    # a class given nothing is compared with every kernel
+                    costs = ([ms for n, ms in able if placed.get(n, c) == c]
+                             or [ms for _, ms in able])
+                    table = statistics.median(costs) if costs else 0.0
                     if table > 0:
                         ratio = table / live
                 scaled[c] = t * ratio

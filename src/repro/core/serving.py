@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import statistics
 import time
 from typing import Callable, Mapping, Sequence
 
@@ -62,8 +63,8 @@ from .cost import Link, MeasuredCostModel
 from .executor import JaxExecutor, SuperStepCache, attach_request_kernels
 from .graph import TaskGraph
 from .simulate import Platform, WorkerAdd, WorkerDrop
-from .spans import (SERVE_ACCOUNT, SERVE_ADMIT, SERVE_ATTACH, SERVE_FEEDBACK,
-                    SERVE_PLAN, SERVE_PREPARE, Spans)
+from .spans import (EXEC_WAIT, SERVE_ACCOUNT, SERVE_ADMIT, SERVE_ATTACH,
+                    SERVE_FEEDBACK, SERVE_PLAN, SERVE_PREPARE, Spans)
 from ..ft.elastic import Heartbeat, HeartbeatMonitor, feed_policy
 
 
@@ -118,6 +119,13 @@ class StepReport:
     request_done_ms: dict = dataclasses.field(default_factory=dict)
     #                               # request retired in the interval -> ms
     #                               # from run_step entry to its last output
+    op_calls: dict = dataclasses.field(default_factory=dict)
+    #                               # kernel op -> executions
+    op_wait_ms: dict = dataclasses.field(default_factory=dict)
+    #                               # kernel op -> exec.wait self ms of the
+    #                               # steps that ran its kernels (fused: the
+    #                               # chain's wait goes to the step that
+    #                               # dispatched it)
 
 
 @dataclasses.dataclass
@@ -188,15 +196,20 @@ class ServeReport:
             "overlap_ms": self.total("overlap_ms"),
             "span_ms": self.span_ms(),
             "request_p90_ms": self.request_p90_ms(),
+            "op_calls": self._summed("op_calls"),
+            "op_wait_ms": self._summed("op_wait_ms"),
         }
+
+    def _summed(self, field: str) -> dict:
+        out: dict = {}
+        for s in self.steps:
+            for name, v in getattr(s, field).items():
+                out[name] = out.get(name, 0) + v
+        return out
 
     def span_ms(self) -> dict[str, float]:
         """Self ms per span name, summed over the steps."""
-        out: dict[str, float] = {}
-        for s in self.steps:
-            for name, ms in s.span_ms.items():
-                out[name] = out.get(name, 0.0) + ms
-        return out
+        return self._summed("span_ms")
 
     def request_p90_ms(self) -> float | None:
         """Nearest-rank p90 of every request's completion time; None when
@@ -478,6 +491,9 @@ class ServingExecutor:
             req_done_ms: dict[str, float] = {}
             pending_events = list(timed)
             pending_admits = sorted(arrival_of.items(), key=lambda kv: (kv[1], kv[0]))
+            # per op: [kernels, exec.wait self s of the steps that ran them]
+            per_op: dict[str, list] = {}
+            wait = spans.totals(EXEC_WAIT)
 
         def fire_due():
             nonlocal decision_ms, redispatched, admitted_late
@@ -522,6 +538,7 @@ class ServingExecutor:
         with spans(SERVE_ACCOUNT):
             fire_due()
             while True:
+                waited = wait[0]
                 run = session.step()
                 if run is None:
                     if session.done():
@@ -566,18 +583,25 @@ class ServingExecutor:
                             if grp is not None:
                                 state.resident[grp] -= g.nodes[n].mem_bytes
                 op = kern.op
+                acc = per_op.get(op)
+                if acc is None:
+                    acc = per_op[op] = [0, 0.0]
+                acc[0] += 1
+                acc[1] += wait[0] - waited
                 self.cost_model.observe(op, self.side, run.group, run.ms)
                 cls_ms.setdefault(run.group, []).append(run.ms)
                 fire_due()
 
         with spans(SERVE_FEEDBACK):
             # heartbeat per class for this interval; EWMAs feed the policy's
-            # live-cost view so the *next* prepare is straggler-aware
+            # live-cost view so the *next* prepare is straggler-aware.  The
+            # median kernel: one host pause inside one kernel's wall time
+            # must not read as a slow class
             t_wall = time.time()
             for cls, samples in cls_ms.items():
-                self.monitor.report(Heartbeat(group=cls, step=step_idx,
-                                              step_time_ms=sum(samples)
-                                              / len(samples), t_wall=t_wall))
+                self.monitor.report(Heartbeat(
+                    group=cls, step=step_idx,
+                    step_time_ms=statistics.median(samples), t_wall=t_wall))
             if hasattr(policy, "observe_step_ms"):
                 feed_policy(policy, self.monitor)
             outputs = (session.result().outputs if self.check is not None
@@ -618,6 +642,8 @@ class ServingExecutor:
                 n_donated=session.n_donated,
                 ran_on=ran_on,
                 request_done_ms=req_done_ms,
+                op_calls={op: a[0] for op, a in per_op.items()},
+                op_wait_ms={op: a[1] * 1e3 for op, a in per_op.items()},
             )
         # every span has closed: their self times partition the wall time
         report.wall_ms = (time.perf_counter() - wall0) * 1e3
@@ -697,6 +723,8 @@ def merge_serve_reports(reports: Sequence[ServeReport],
         span_ms: dict[str, float] = {}
         span_calls: dict[str, int] = {}
         done_ms: dict[str, float] = {}
+        op_calls: dict[str, int] = {}
+        op_wait_ms: dict[str, float] = {}
         for s in group:
             for cls, ms in s.kernel_ms_by_class.items():
                 classes.setdefault(cls, []).append(ms)
@@ -711,6 +739,10 @@ def merge_serve_reports(reports: Sequence[ServeReport],
             for name, n in s.span_calls.items():
                 span_calls[name] = span_calls.get(name, 0) + n
             done_ms.update(s.request_done_ms)
+            for op, n in s.op_calls.items():
+                op_calls[op] = op_calls.get(op, 0) + n
+            for op, ms in s.op_wait_ms.items():
+                op_wait_ms[op] = op_wait_ms.get(op, 0.0) + ms
 
         def tot(field: str):
             return sum(getattr(s, field) for s in group)
@@ -750,5 +782,7 @@ def merge_serve_reports(reports: Sequence[ServeReport],
             span_ms=span_ms,
             span_calls=span_calls,
             request_done_ms=done_ms,
+            op_calls=op_calls,
+            op_wait_ms=op_wait_ms,
         ))
     return merged

@@ -25,7 +25,8 @@ to the helper's own cost (about a microsecond per span).
 ``exec.pull``       host time in ``device_put`` pulls (demand and prefetch)
 ``exec.wait``       the host blocked on the device (``block_until_ready``)
 ``exec.launch``     enqueueing a kernel or chain (jit dispatch); its trace
-                    event carries the ``kernel`` and its ``req``
+                    event carries the ``kernel``, its ``op`` (``superstep``
+                    for a fused chain) and its ``req``
 ``exec.compile``    a fused chain's compile (a ``SuperStepCache`` miss)
 ``exec.account``    the rest of ``ExecSession.step``: virtual clock, channel
                     drains, prefetch planning, output bookkeeping
@@ -78,6 +79,14 @@ class Spans:
         if span is None:
             span = self._named[name] = _Span(self, name, {})
         return span
+
+    def totals(self, name: str) -> list:
+        """The live ``[self s, calls]`` of ``name``: a caller that reads it
+        around a stretch of work gets that stretch's self time."""
+        totals = self._acc.get(name)
+        if totals is None:
+            totals = self._acc[name] = [0.0, 0]
+        return totals
 
     @property
     def ms(self) -> dict[str, float]:

@@ -9,6 +9,7 @@ for kernel tests, pure-jnp oracles otherwise (this CPU container).
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -70,6 +71,48 @@ def matadd(a, b):
     b2, _ = _pad_to(b2, 128, 1)
     o = _ma.matadd(a2, b2, interpret=interp)
     return o[: a.shape[0], : a.shape[1]]
+
+
+def gemm_nt(c, a, b):
+    """c - a @ b.T (the tile Cholesky's gemm; its syrk passes b = a)."""
+    use, interp = _use_pallas()
+    if not use:
+        return _ref.gemm_nt(c, a, b)
+    c2, _ = _pad_to(c, 128, 0)
+    c2, _ = _pad_to(c2, 128, 1)
+    a2, _ = _pad_to(a, 128, 0)
+    a2, _ = _pad_to(a2, 128, 1)
+    b2, _ = _pad_to(b, 128, 0)
+    b2, _ = _pad_to(b2, 128, 1)
+    o = _mm.gemm_nt(c2, a2, b2, interpret=interp)
+    return o[: c.shape[0], : c.shape[1]]
+
+
+# The tile Cholesky's panel ops are XLA's own, on every backend; jitted
+# under these names so that a trace shows the modules jit_potrf, jit_trsm
+# and jit_diag_shift.
+
+@jax.jit
+def potrf(a):
+    """Lower Cholesky factor of ``a``, reading its lower triangle only (as
+    LAPACK's potrf); the upper triangle of the result is zero."""
+    return jax.lax.linalg.cholesky(a, symmetrize_input=False)
+
+
+@jax.jit
+def trsm(b, l):
+    """b @ inv(l).T for lower-triangular ``l``: the solve x @ l.T = b."""
+    return jax.lax.linalg.triangular_solve(l, b, left_side=False, lower=True,
+                                           transpose_a=True)
+
+
+@functools.partial(jax.jit, static_argnames="shift")
+def diag_shift(x, shift):
+    """tril(x) + tril(x, -1).T + shift * I: the symmetric tile that ``x``'s
+    lower triangle defines, its diagonal raised by ``shift``."""
+    low = jnp.tril(x, -1)
+    eye = jnp.eye(x.shape[0], dtype=x.dtype)
+    return low + low.T + (jnp.diagonal(x) + shift)[:, None] * eye
 
 
 def flash_attention(q, k, v, *, causal=True, kv_len=None):

@@ -21,6 +21,10 @@ def matadd(a: jax.Array, b: jax.Array) -> jax.Array:
     return a + b
 
 
+def gemm_nt(c: jax.Array, a: jax.Array, b: jax.Array) -> jax.Array:
+    return (c - jnp.dot(a, b.T, preferred_element_type=jnp.float32)).astype(c.dtype)
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     kv_len: int | None = None) -> jax.Array:
     """q: (B, H, Sq, hd); k/v: (B, H, Sk, hd) — MHA layout (GQA callers

@@ -60,6 +60,25 @@ def test_choose_blocks_picks(M, N, K, itemsize, expect):
     assert choose_blocks(M, N, K, itemsize) == expect
 
 
+@pytest.mark.parametrize("M, N, K, itemsize, matmul_blocks", [
+    (128, 128, 128, 4, (128, 128, 128)),
+    (2048, 2048, 2048, 4, (1024, 1024, 512)),
+    (2048, 2048, 2048, 2, (1024, 1024, 512)),
+    (4096, 4096, 4096, 8, (512, 1024, 512))])
+def test_choose_blocks_with_c_leaves_the_matmul_alone(M, N, K, itemsize, matmul_blocks):
+    """Counting gemm_nt's C block changes nothing for the matmul's shapes;
+    gemm_nt's own blocks fit the VMEM limit with C double-buffered, and at
+    2048 f32 they are the matmul's."""
+    assert choose_blocks(M, N, K, itemsize) == matmul_blocks
+    assert choose_blocks(M, N, K, itemsize, reads_c=False) == matmul_blocks
+    assert vmem_bytes(*matmul_blocks, itemsize) == vmem_bytes(
+        *matmul_blocks, itemsize, reads_c=False)
+    blocks = choose_blocks(M, N, K, itemsize, reads_c=True)
+    assert vmem_bytes(*blocks, itemsize, reads_c=True) <= VMEM_LIMIT_BYTES
+    if (M, itemsize) == (2048, 4):
+        assert blocks == matmul_blocks
+
+
 @pytest.mark.parametrize("shape", [(256, 256), (512, 384), (64, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
 def test_matadd_sweep(shape, dtype):

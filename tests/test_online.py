@@ -3,6 +3,7 @@
 Plain pytest — must run without hypothesis (the tier-1 floor)."""
 
 import math
+import statistics
 
 import pytest
 
@@ -13,6 +14,7 @@ from repro.core.graph import Kernel, generate_paper_dag
 from repro.core.online import IncrementalGpPolicy, OnlinePartitioner
 from repro.core.simulate import (Platform, Processor, WorkerDrop, simulate,
                                  make_cpu_gpu_platform)
+from repro.ft.elastic import Heartbeat, HeartbeatMonitor, feed_policy
 
 KV = 1 << 20
 
@@ -171,3 +173,68 @@ def test_incremental_policy_carries_assignments_across_stream():
             assert carried / len(common) >= 0.9
         prev_assignment = dict(pol.assignment)
     assert pol.stats["prepare_warm"] == len(stream) - 1
+
+
+# -- measured feedback on a resubmitted static DAG -----------------------------
+
+def _tile_graph():
+    from repro.core.graph import tile_cholesky_dag
+
+    return paper_calibrated_model().weight_graph(
+        tile_cholesky_dag(6, shift=3.0),
+        {op: 512 for op in ("diag_shift", "potrf", "trsm", "syrk", "gemm")})
+
+
+def test_feedback_ratio_compares_a_class_with_the_kernels_it_ran():
+    """Classes that run exactly as the table says keep the static targets,
+    whatever mix of ops each was given."""
+    g = _tile_graph()
+    plat = make_cpu_gpu_platform()
+    pol = IncrementalGpPolicy(scale_by_workers=True)
+    pol.prepare(g.copy(), plat)
+    ran: dict[str, list[float]] = {}
+    for n, c in pol.assignment.items():
+        if g.nodes[n].op != "source":
+            ran.setdefault(c, []).append(g.nodes[n].costs[c])
+    assert {g.nodes[n].op for n, c in pol.assignment.items() if c == "cpu"} \
+        != {g.nodes[n].op for n, c in pol.assignment.items() if c == "gpu"}
+    pol.observe_step_ms({c: statistics.median(v) for c, v in ran.items()})
+    live = pol._targets_for(g, plat)
+    static = pol.targets_for(g, plat)
+    assert live == pytest.approx(static)
+
+
+@pytest.mark.parametrize("slow", ["cpu", "gpu"])
+def test_straggler_on_a_resubmitted_static_dag_is_rebalanced(slow):
+    """The executor's heartbeats (median kernel ms per class) of a class
+    that turns 3x slower shrink its target, and the resubmitted DAG ends
+    balanced under the trigger; a host pause in one kernel moves nothing."""
+    g = _tile_graph()
+    plat = make_cpu_gpu_platform()
+    pol = IncrementalGpPolicy(scale_by_workers=True)
+    mon = HeartbeatMonitor(list(plat.classes))
+    # both classes alias one device: the same ms per op, whatever the table
+    ms = {"diag_shift": 0.6, "potrf": 1.6, "trsm": 1.8, "syrk": 1.0, "gemm": 1.0}
+    before = None
+    for i in range(16):
+        pol.prepare(g.copy(), plat)
+        if i == 5:
+            before = dict(pol.targets)
+            rev = pol.revision
+        if 5 <= i < 8:
+            assert pol.revision == rev  # steady: no repartition
+        factor = 3.0 if i >= 8 else 1.0
+        ran: dict[str, list[float]] = {}
+        for n, c in pol.assignment.items():
+            if g.nodes[n].op != "source":
+                ran.setdefault(c, []).append(
+                    ms[g.nodes[n].op] * (factor if c == slow else 1.0))
+        ran["cpu"][0] += 40.0  # a host pause inside one kernel's wall time
+        for c, v in ran.items():
+            mon.report(Heartbeat(group=c, step=i,
+                                 step_time_ms=statistics.median(v), t_wall=0.0))
+        feed_policy(pol, mon)
+    p = pol.partitioner
+    odds = lambda t: t[slow] / (1.0 - t[slow])  # noqa: E731
+    assert odds(pol.targets) < odds(before) / 2.0  # ~3x slower, ~3x less
+    assert p.imbalance() <= p.imbalance_trigger + 1e-9

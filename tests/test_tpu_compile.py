@@ -20,7 +20,7 @@ from repro.core.graph import TaskGraph
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.matadd import matadd
-from repro.kernels.matmul import matmul
+from repro.kernels.matmul import gemm_nt, matmul
 from repro.kernels.wkv6 import wkv6
 
 SIDE = 2048  # chip_smoke.py's kernel side: one 16 MiB f32 block
@@ -79,6 +79,31 @@ def test_matmul_is_one_kernel_named_matmul(one_chip, dtype):
     work = [name for name, rest in ops_ if "parameter(" not in rest]
     assert len(work) == 1 and re.fullmatch(r"matmul\.\d+", work[0])
     assert 'custom_call_target="tpu_custom_call"' in dict(ops_)[work[0]]
+
+
+def test_gemm_nt_is_one_kernel_named_gemm_nt(one_chip):
+    """The tile Cholesky's update C - A @ B^T at 2048 f32: the Pallas call
+    alone (no transpose of B beside it), named ``gemm_nt.<n>`` as the
+    benchmark's roofline reader matches it."""
+    sq = ((SIDE, SIDE), jnp.float32)
+    text = _compile(gemm_nt, [sq, sq, sq], one_chip).as_text()
+    entry = text[text.index("\nENTRY "):]
+    ops_ = re.findall(r"^\s+(?:ROOT )?%(\S+) = (.*)$", entry, re.M)
+    work = [name for name, rest in ops_ if "parameter(" not in rest]
+    assert len(work) == 1 and re.fullmatch(r"gemm_nt\.\d+", work[0])
+    assert 'custom_call_target="tpu_custom_call"' in dict(ops_)[work[0]]
+
+
+@pytest.mark.parametrize("op", ["potrf", "trsm", "diag_shift"])
+def test_tile_panel_ops_compile_for_v5e(one_chip, op):
+    """XLA's Cholesky and triangular solve of a 2048 f32 tile, and the
+    diagonal tile's symmetrization, for the described chip."""
+    sq = ((SIDE, SIDE), jnp.float32)
+    fn = {"potrf": ops.potrf, "trsm": ops.trsm,
+          "diag_shift": lambda x: ops.diag_shift(x, shift=3.0)}[op]
+    n_args = 2 if op == "trsm" else 1
+    compiled = _compile(fn, [sq] * n_args, one_chip)
+    assert compiled.out_info.shape == (SIDE, SIDE)
 
 
 def test_matadd_compiles_for_v5e(one_chip):
