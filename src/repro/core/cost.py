@@ -244,6 +244,10 @@ class MeasuredCostModel:
         self._cache[key] = cur
         return cur
 
+    def observed_ms(self, op: str, n: int, cls: str) -> float | None:
+        """The history's estimate for (op, n, cls), or None; never measures."""
+        return self._cache.get((op, n, cls))
+
     def kernel_ms(self, op: str, n: int, cls: str) -> float:
         key = (op, n, cls)
         if key not in self._cache:

@@ -31,9 +31,10 @@ Two entry points:
 * :meth:`JaxExecutor.run` — one-shot batch execution (unchanged API);
 * :class:`ExecSession` — the *online* form: kernels execute one
   :meth:`~ExecSession.step` at a time, the assignment can be rewritten
-  between steps (:meth:`~ExecSession.reassign`), per-kernel wall times are
-  measured (``time_kernels=True``), and a group that leaves the platform is
-  evicted (:meth:`~ExecSession.evict_group`): its block copies are lost and
+  between steps (:meth:`~ExecSession.reassign`), a sample of the kernels'
+  wall times is measured (``time_kernels=True``, see *Sampled timing*
+  below), and a group that leaves the platform is evicted
+  (:meth:`~ExecSession.evict_group`): its block copies are lost and
   any producer whose output a pending consumer still needs is transparently
   re-queued for re-execution — the executor-land analogue of the simulator's
   in-flight abort + re-dispatch on :class:`~repro.core.simulate.WorkerDrop`.
@@ -41,10 +42,24 @@ Two entry points:
   from the consistency *and* the comm model, so the consumer's re-pull is
   charged again (the transfer really does happen twice).
 
+**Sampled timing** (``time_kernels=True``, unfused): a host sync per kernel
+would keep the device idle through every round trip, so only a fixed sample
+is timed — each group's first executed kernel and every
+``max(1, n_g // TIMED_PER_GROUP)``-th after it, ``n_g`` the kernels the
+group was given at session start (about five a group; a group of 9 or fewer
+is timed in full).  A timed kernel first waits on its inputs and on the last
+output launched to its device (the device runs in order, so its queue is
+drained), then its launch and run are timed up to its output's
+``block_until_ready``.  Every other kernel launches asynchronously and is
+never waited on; its ``KernelRun.ms`` is an estimate (the last timed ms of
+its (op, group), else :attr:`ExecSession.op_ms` as the caller seeded it,
+else 0) that keeps the virtual timeline moving, and it stays out of
+``ExecResult.kernel_ms``.
+
 **Fused super-steps** (``fused=True``): instead of the Python-driven
-kernel-at-a-time loop — one async dispatch plus (with ``time_kernels``) one
-host sync *per kernel* — the session assembles each partition group's
-currently-runnable intra-group kernel chain into a single jitted,
+kernel-at-a-time loop — one async dispatch per kernel, plus (with
+``time_kernels``) the sampled syncs — the session assembles each partition
+group's currently-runnable intra-group kernel chain into a single jitted,
 buffer-donating callable (:func:`repro.kernels.ops.build_chain` composed per
 the graph's topological order) and dispatches it as ONE XLA computation with
 one ready-barrier per group-step.  Per-kernel wall times are *apportioned*
@@ -98,6 +113,9 @@ from .spans import (
 )
 from ..kernels.ops import build_chain
 
+# sampled timing: about this many timed kernels per group and session
+TIMED_PER_GROUP = 5
+
 
 @dataclasses.dataclass
 class ExecResult:
@@ -107,7 +125,7 @@ class ExecResult:
     bytes_transferred: int
     kernels_per_group: dict
     kernel_ms: dict = dataclasses.field(default_factory=dict)
-    #                                   # kernel -> wall ms (time_kernels=True)
+    #                                   # kernel -> wall ms of the timed kernels
     reexecuted: list = dataclasses.field(default_factory=list)
     #                                   # kernels re-run after group eviction
     model_makespan_ms: float = 0.0  # two-resource virtual-clock makespan
@@ -195,12 +213,14 @@ class KernelRun:
 
     name: str
     group: str
-    ms: float  # wall ms (0.0 unless the session times kernels)
+    ms: float  # wall ms if timed, else an estimate (0.0 with time_kernels off)
     n_transfers: int  # transfers this kernel's input gather caused
     nbytes: int  # bytes those transfers moved
     t_start: float = 0.0  # virtual start (comm model attached)
     t_finish: float = 0.0  # virtual finish (compute + overlapped transfers)
-    t_ready: float = 0.0  # perf_counter once its output was ready (or launched)
+    t_ready: float = 0.0  # perf_counter once its output was ready (timed),
+    #                       else once its launch returned
+    timed: bool = False  # ms was measured (a sampled or fused-apportioned run)
 
 
 class ExecSession:
@@ -216,6 +236,9 @@ class ExecSession:
     tier of a hierarchical topology) and kernels get virtual start/finish
     times with transfers overlapping compute (``prefetch_depth`` next-ready
     kernels have their inputs staged early).
+
+    ``time_kernels`` times a fixed sample of the kernels (module docstring,
+    *Sampled timing*); :attr:`op_ms` holds the estimates of the others.
 
     ``spans`` records the session's host time by span name
     (:mod:`repro.core.spans`); the serving loop passes its own recorder.
@@ -313,6 +336,17 @@ class ExecSession:
         self.reexecuted: list[str] = []
         self._order = [n for n in g.topo_order() if g.nodes[n].op != "source"]
         self._done: set[str] = set()
+        # sampled timing: a group's kernels at session start set its stride
+        n_g: dict[str, int] = {}
+        for n in self._order:
+            grp = self.assignment.get(n, self.host_group)
+            n_g[grp] = n_g.get(grp, 0) + 1
+        self._stride = {grp: max(1, c // TIMED_PER_GROUP) for grp, c in n_g.items()}
+        self._ran: dict[str, int] = {}  # group -> kernels executed
+        self._last_out: dict = {}  # device -> last output launched to it
+        # (op, group) -> ms: an untimed kernel's estimate.  Each timed kernel
+        # writes it; the caller may seed it (e.g. from a measured cost model)
+        self.op_ms: dict[tuple[str, str], float] = {}
         self._t0 = time.perf_counter()
 
     # -- state ---------------------------------------------------------------
@@ -901,7 +935,7 @@ class ExecSession:
             if record:
                 buf_append(
                     KernelRun(
-                        n, grp, kms, per_nt[i], per_nb[i], vstart, vfinish, t_ready
+                        n, grp, kms, per_nt[i], per_nb[i], vstart, vfinish, t_ready, tk
                     )
                 )
         self.per_group[grp] = self.per_group.get(grp, 0) + len(members)
@@ -1273,7 +1307,7 @@ class ExecSession:
                     buf_append(
                         KernelRun(
                             n, grp, kms, pl["per_nt"][i], pl["per_nb"][i],
-                            vstart, vfinish, t_ready,
+                            vstart, vfinish, t_ready, tk,
                         )
                     )
             self.per_group[grp] = self.per_group.get(grp, 0) + len(pl["members"])
@@ -1326,11 +1360,21 @@ class ExecSession:
             if k.fn is None:
                 raise ValueError(f"kernel {name} has no fn")
             ms = 0.0
+            timed = False
             if self.time_kernels:
+                i = self._ran.get(grp, 0)
+                self._ran[grp] = i + 1
+                timed = i % self._stride.get(grp, 1) == 0
+            if timed:
+                # start on a drained device with ready inputs, so the time is
+                # this kernel's launch and run, not the queued work before it
                 with spans(EXEC_WAIT):
                     for a in args:
                         if hasattr(a, "block_until_ready"):
                             a.block_until_ready()
+                    last = self._last_out.get(dev)
+                    if last is not None:
+                        last.block_until_ready()
                 t0 = time.perf_counter()
             req = k.meta.get("req", "")
             with (
@@ -1338,15 +1382,20 @@ class ExecSession:
                 jax.default_device(dev),
             ):
                 out = k.fn(*args)
-            if self.time_kernels:
+            if timed:
                 with spans(EXEC_WAIT):
                     if hasattr(out, "block_until_ready"):
                         out.block_until_ready()
                     t_ready = time.perf_counter()
                 ms = (t_ready - t0) * 1e3
                 self.kernel_ms[name] = ms
+                self.op_ms[(k.op, grp)] = ms
             else:
                 t_ready = time.perf_counter()
+                if self.time_kernels:
+                    ms = self.op_ms.get((k.op, grp), 0.0)
+            if self.time_kernels and hasattr(out, "block_until_ready"):
+                self._last_out[dev] = out
             vstart = vfinish = 0.0
             if self.comm is not None:
                 vstart = max(
@@ -1367,7 +1416,7 @@ class ExecSession:
             self.per_group[grp] = self.per_group.get(grp, 0) + 1
             self._done.add(name)
             self._prefetch_ready()
-            return KernelRun(name, grp, ms, nt, nb, vstart, vfinish, t_ready)
+            return KernelRun(name, grp, ms, nt, nb, vstart, vfinish, t_ready, timed)
 
     def run_all(self) -> None:
         if self.fused:

@@ -18,7 +18,9 @@ measured hardware:
   Formula (1)/(2) over the survivors, a fully-dead class has its device-group
   memory evicted (lost blocks transparently recomputed) and its pending
   kernels re-dispatched onto live groups;
-* the **measurement loop closes**: each kernel's observed wall time updates a
+* the **measurement loop closes**: each timed kernel's observed wall time
+  (a fixed sample of about five a class, see :mod:`repro.core.executor`,
+  *Sampled timing*) updates a
   :class:`~repro.core.cost.MeasuredCostModel` history and per-class
   :class:`~repro.ft.elastic.HeartbeatMonitor` EWMAs, which feed
   ``IncrementalGpPolicy._targets_for`` — partition targets track *observed*
@@ -49,6 +51,7 @@ the cache, so only a full-repartition escalation recompiles everything.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 import statistics
@@ -83,7 +86,7 @@ class StepReport:
     admitted_late: int              # tasks admitted after t=0 (arrival gate)
     redispatched: int               # pending kernels moved off a dead group
     reexecuted: int                 # finished kernels re-run after eviction
-    kernel_ms_by_class: dict        # class -> mean observed kernel ms
+    kernel_ms_by_class: dict        # class -> mean timed kernel ms
     dropped: list
     added: list
     events_missed: list             # events past the interval's drain clock
@@ -110,6 +113,8 @@ class StepReport:
     #                               # one per wave, else one per group-step)
     overlap_ms: float = 0.0         # compute co-scheduled inside waves
     n_donated: int = 0              # donated buffers the fused calls consumed
+    timed_kernels: int = 0          # kernel executions whose wall ms was
+    #                               # measured (a sample; fused: every one)
     # kernel -> the group its last execution ran on (kept out of to_dict)
     ran_on: dict = dataclasses.field(default_factory=dict, repr=False)
     span_ms: dict = dataclasses.field(default_factory=dict)
@@ -118,14 +123,16 @@ class StepReport:
     #                               # span name -> spans closed
     request_done_ms: dict = dataclasses.field(default_factory=dict)
     #                               # request retired in the interval -> ms
-    #                               # from run_step entry to its last output
+    #                               # from run_step entry until the host saw
+    #                               # its last output ready
     op_calls: dict = dataclasses.field(default_factory=dict)
     #                               # kernel op -> executions
     op_wait_ms: dict = dataclasses.field(default_factory=dict)
     #                               # kernel op -> exec.wait self ms of the
     #                               # steps that ran its kernels (fused: the
     #                               # chain's wait goes to the step that
-    #                               # dispatched it)
+    #                               # dispatched it), plus its share of the
+    #                               # final wait on the exits
 
 
 @dataclasses.dataclass
@@ -172,6 +179,7 @@ class ServeReport:
             "total_makespan_ms": self.total("makespan_ms"),
             "wall_ms": self.total("wall_ms"),
             "kernels": int(self.total("n_kernels")),
+            "timed_kernels": int(self.total("timed_kernels")),
             "transfers": int(self.total("n_transfers")),
             "bytes_moved": int(self.total("bytes_transferred")),
             "offline_ms": self.total("offline_ms"),
@@ -469,6 +477,12 @@ class ServingExecutor:
                 streaming=self.streaming, chunk_bytes=self.chunk_bytes,
                 stream_depth=self.stream_depth,
                 async_groups=self.async_groups, spans=spans)
+            # an untimed kernel's estimate starts from the measured history
+            for op in {k.op for k in g.nodes.values()}:
+                for cls in self.executor.groups:
+                    ms = self.cost_model.observed_ms(op, self.side, cls)
+                    if ms is not None:
+                        session.op_ms[(op, cls)] = ms
 
             clock = 0.0
             decision_ms = 0.0
@@ -489,6 +503,10 @@ class ServingExecutor:
                     req_tasks.setdefault(r, []).append(n)
             req_left = {r: len(v) for r, v in req_tasks.items()}
             req_done_ms: dict[str, float] = {}
+            # device -> (request, last output) retired by an untimed kernel
+            # and not yet seen ready, oldest first
+            unready: dict = {}
+            n_timed = 0
             pending_events = list(timed)
             pending_admits = sorted(arrival_of.items(), key=lambda kv: (kv[1], kv[0]))
             # per op: [kernels, exec.wait self s of the steps that ran them]
@@ -574,10 +592,25 @@ class ServingExecutor:
                     if (state.resident[run.group]
                             > platform.mem_cap_of(run.group) + 1e-6):
                         spills += 1
+                # a request is done when the host sees its output ready: a
+                # timed kernel's drain readied every earlier output on its
+                # device; otherwise poll the oldest, without blocking
+                dev = self.executor.groups[run.group]
+                q = unready.get(dev)
+                if q and run.timed:
+                    t_drained = (run.t_ready - run.ms / 1e3 - wall0) * 1e3
+                    while q:
+                        req_done_ms[q.popleft()[0]] = t_drained
+                while q and q[0][1].is_ready():
+                    req_done_ms[q.popleft()[0]] = (time.perf_counter() - wall0) * 1e3
                 if first and r is not None and r in req_left:
                     req_left[r] -= 1
                     if req_left[r] == 0:  # request retired: free its KV
-                        req_done_ms[r] = (run.t_ready - wall0) * 1e3
+                        if run.timed:
+                            req_done_ms[r] = (run.t_ready - wall0) * 1e3
+                        else:
+                            unready.setdefault(dev, collections.deque()).append(
+                                (r, session.blocks[run.name]))
                         for n in req_tasks[r]:
                             grp = state.task_group.pop(n, None)
                             if grp is not None:
@@ -588,8 +621,10 @@ class ServingExecutor:
                     acc = per_op[op] = [0, 0.0]
                 acc[0] += 1
                 acc[1] += wait[0] - waited
-                self.cost_model.observe(op, self.side, run.group, run.ms)
-                cls_ms.setdefault(run.group, []).append(run.ms)
+                if run.timed:
+                    n_timed += 1
+                    self.cost_model.observe(op, self.side, run.group, run.ms)
+                    cls_ms.setdefault(run.group, []).append(run.ms)
                 fire_due()
 
         with spans(SERVE_FEEDBACK):
@@ -604,8 +639,20 @@ class ServingExecutor:
                     step_time_ms=statistics.median(samples), t_wall=t_wall))
             if hasattr(policy, "observe_step_ms"):
                 feed_policy(policy, self.monitor)
-            outputs = (session.result().outputs if self.check is not None
-                       else None)
+            # return only once the exits are ready, check hook or not; that
+            # wait is host time blocked on the exits, split over their ops
+            waited = wait[0]
+            result = session.result()
+            for n in result.outputs:
+                per_op[g.nodes[n].op][1] += (wait[0] - waited) / len(result.outputs)
+            left = [item for q in unready.values() for item in q]
+            if left:
+                with spans(EXEC_WAIT):
+                    jax.block_until_ready([out for _, out in left])
+                t_left = (time.perf_counter() - wall0) * 1e3
+                for req, _ in left:
+                    req_done_ms[req] = t_left
+            outputs = result.outputs if self.check is not None else None
 
             report = StepReport(
                 tag=step.tag,
@@ -640,6 +687,7 @@ class ServingExecutor:
                 n_waves=session.n_waves,
                 overlap_ms=session.overlap_ms,
                 n_donated=session.n_donated,
+                timed_kernels=n_timed,
                 ran_on=ran_on,
                 request_done_ms=req_done_ms,
                 op_calls={op: a[0] for op, a in per_op.items()},
@@ -779,6 +827,7 @@ def merge_serve_reports(reports: Sequence[ServeReport],
             stream_busy_ms=tot("stream_busy_ms"),
             n_waves=int(tot("n_waves")),
             overlap_ms=tot("overlap_ms"),
+            timed_kernels=int(tot("timed_kernels")),
             span_ms=span_ms,
             span_calls=span_calls,
             request_done_ms=done_ms,
